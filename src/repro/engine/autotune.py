@@ -6,11 +6,12 @@ on the tree geometry (cut/leaf buckets set the operand matrices) and on
 whether the platform compiles Pallas at all (TPU) or runs it in interpret
 mode (CPU/GPU dev boxes).  This module owns that decision:
 
-* :func:`autotune_fused` sweeps a tile grid against a sample batch,
-  validates every candidate bit-identically against the numpy oracle
-  (``kernels/ref.fused_ingest_ref``), *probes compiled (non-interpret)
-  execution first* and falls back to interpret — recording which mode ran,
-  never silently substituting — then persists the fastest valid config.
+* :func:`autotune_fused` sweeps a tile grid against a sample batch in the
+  platform's one mode (compiled on a TPU, interpret elsewhere), validates
+  every candidate bit-identically against the numpy oracle
+  (``kernels/ref.fused_ingest_ref``), reports a candidate that fails to
+  compile as a ``failed`` row — it is never retried on a slower path —
+  then persists the fastest valid config with the mode it ran in.
 * :func:`lookup` / :func:`record` read/write the persisted store, keyed by
   ``(backend, geometry-bucket)``; ``PallasBackend.fused_ingest`` consults
   it when the caller does not pin tiles explicitly.
@@ -31,7 +32,7 @@ from typing import Optional
 
 import numpy as np
 
-from repro.engine.plan import LANE, pad_bucket
+from repro.engine.plan import LANE, interpret_default, pad_bucket
 
 _ROOT = pathlib.Path(__file__).resolve().parents[3]
 DEFAULT_STORE = _ROOT / "results" / "autotune_tiles.json"
@@ -53,7 +54,7 @@ class TileConfig:
 
     tile_m: int
     tile_l: int
-    interpret: bool  # True ⇒ compiled pallas unavailable, fallback recorded
+    interpret: bool  # the mode the tiles were timed in (lookups match it)
     records_per_s: float = 0.0
     source: str = "autotune"
 
@@ -126,11 +127,10 @@ def autotune_fused(
 ) -> dict:
     """Sweep fused-ingest tile shapes on the pallas backend; persist the win.
 
-    Every candidate is validated bit-identically against the numpy oracle
-    before it may win.  Compiled (non-interpret) execution is probed first
-    for each tile shape; when the platform cannot compile Pallas the
-    candidate reruns in interpret mode and the row records
-    ``mode="interpret"`` — the fallback is explicit, never silent.
+    Every candidate runs in the platform's one mode (``interpret_default``:
+    compiled on a TPU, interpreted elsewhere) and is validated
+    bit-identically against the numpy oracle before it may win.  A
+    candidate that raises is a ``failed`` row carrying its error.
     """
     from repro.engine.engine import engine_for
     from repro.kernels.ref import fused_ingest_ref
@@ -138,52 +138,45 @@ def autotune_fused(
     engine = engine_for(tree)
     oracle_bids, oracle_partial = fused_ingest_ref(tree, records)
     geom = geometry_key(tree)
+    interpret = interpret_default()
+    mode = "interpret" if interpret else "compiled"
     rows = []
     for tile_m, tile_l in tile_grid:
         row: dict = {"tile_m": int(tile_m), "tile_l": int(tile_l)}
-        result = None
-        for interpret in (False, True):
-            try:
-                bids, partial = engine.fused_step(
-                    records, backend="pallas", tile_m=tile_m,
-                    tile_l=tile_l, interpret=interpret,
-                )
-                result = (bids, partial, interpret)
-                break
-            except Exception as exc:  # lowering/compile unsupported here
-                row["compile_error"] = f"{type(exc).__name__}: {exc}"[:200]
-        if result is None:
+
+        def step():
+            return engine.fused_step(
+                records, backend="pallas", tile_m=tile_m, tile_l=tile_l,
+                interpret=interpret,
+            )
+
+        try:
+            bids, partial = step()
+        except Exception as exc:  # reported, never retried elsewhere
             row["mode"] = "failed"
             row["valid"] = False
+            row["compile_error"] = f"{type(exc).__name__}: {exc}"[:200]
             rows.append(row)
             continue
-        bids, partial, interpret = result
-        row["mode"] = "interpret" if interpret else "compiled"
+        row["mode"] = mode
         row["valid"] = bool(
             np.array_equal(bids, oracle_bids)
         ) and _partials_identical(partial, oracle_partial)
         if row["valid"]:
             t0 = time.perf_counter()
             for _ in range(reps):
-                engine.fused_step(
-                    records, backend="pallas", tile_m=tile_m,
-                    tile_l=tile_l, interpret=interpret,
-                )
+                step()
             dt = (time.perf_counter() - t0) / reps
             row["records_per_s"] = float(records.shape[0] / dt)
         rows.append(row)
     valid = [r for r in rows if r.get("valid")]
     chosen = None
     if valid:
-        # compiled rows outrank interpret rows; speed breaks ties
-        best = max(
-            valid,
-            key=lambda r: (r["mode"] == "compiled", r["records_per_s"]),
-        )
+        best = max(valid, key=lambda r: r["records_per_s"])
         chosen = TileConfig(
             tile_m=best["tile_m"],
             tile_l=best["tile_l"],
-            interpret=best["mode"] == "interpret",
+            interpret=interpret,
             records_per_s=best["records_per_s"],
         )
         if persist:

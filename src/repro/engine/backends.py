@@ -551,7 +551,7 @@ class JaxBackend(Backend):
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU kernels (interpret mode off-TPU)
+# Pallas TPU kernels (compiled on a TPU, interpret mode elsewhere)
 # ---------------------------------------------------------------------------
 @functools.partial(
     jax.jit,
@@ -678,6 +678,14 @@ class PallasBackend(Backend):
         sig = planlib.tree_signature(tree)
         cut_bucket = pad_bucket(tree.cuts.n_cuts, LANE)
         leaf_bucket = pad_bucket(tree.n_leaves, LANE)
+        from repro.kernels.fused_ingest import MAX_LEAF_BUCKET
+
+        if leaf_bucket > MAX_LEAF_BUCKET:
+            raise ValueError(
+                f"the fused pallas kernel holds at most {MAX_LEAF_BUCKET} "
+                f"(padded) leaves in VMEM; this tree has {tree.n_leaves} "
+                f"(bucket {leaf_bucket}) — ingest it on backend='jax'"
+            )
         tile_l = min(tile_l, leaf_bucket)
         if leaf_bucket % tile_l:  # non-divisor tile (autotuned oddball)
             tile_l = LANE
@@ -722,6 +730,8 @@ class PallasBackend(Backend):
             from repro.engine import autotune
 
             cfg = autotune.lookup("pallas", autotune.geometry_key(tree))
+            if cfg is not None and cfg.interpret != interpret:
+                cfg = None  # tiles timed in the other mode say nothing here
             tile_m = tile_m or (cfg.tile_m if cfg else 256)
             tile_l = tile_l or (cfg.tile_l if cfg else LANE)
         plan = self._ingest_plan(tree, cache, tile_m, tile_l, interpret)

@@ -67,11 +67,16 @@ def pad_bucket(n: int, minimum: int = 1) -> int:
     return 1 << (target - 1).bit_length()
 
 
-def interpret_default() -> bool:
-    """Pallas kernels run in interpret mode wherever there is no TPU."""
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU."""
     import jax
 
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "tpu"
+
+
+def interpret_default() -> bool:
+    """Pallas kernels run in interpret mode wherever there is no TPU."""
+    return not on_tpu()
 
 
 def tree_signature(tree: FrozenQdTree) -> int:
@@ -166,6 +171,10 @@ class PlanCache:
     def __len__(self) -> int:
         with self._lock:
             return len(self._plans)
+
+    def keys(self) -> list:
+        with self._lock:
+            return list(self._plans)
 
     def evict(self, predicate: Callable[[Any], bool]) -> int:
         """Drop every entry whose key satisfies ``predicate``."""

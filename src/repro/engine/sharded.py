@@ -107,21 +107,34 @@ def resolve_executor(
     :class:`PerformanceWarning` citing the measured 0.44× regression,
     but is honored: shared-plan thread shards remain the right tool for
     deterministic tests and for custom ``Executor`` protocols.
+
+    On a TPU host every shard runs in this process: a chip belongs to
+    one process, and spawn workers import JAX, so they could neither
+    share the parent's chip nor reach another.  ``None`` resolves to
+    ``"thread"`` there, and a process executor raises ``ValueError``.
     """
+    tpu = planlib.on_tpu()
     if executor is None:
-        return "process" if n_shards >= 2 else "thread"
-    if isinstance(executor, str):
-        if executor not in ("thread", "process"):
-            raise ValueError(
-                f"executor must be 'thread', 'process', an Executor, or "
-                f"None — got {executor!r}"
-            )
-        if executor == "thread" and n_shards > 1:
-            warnings.warn(
-                _THREAD_FOOTGUN.format(k=n_shards),
-                PerformanceWarning,
-                stacklevel=stacklevel,
-            )
+        return "process" if n_shards >= 2 and not tpu else "thread"
+    if isinstance(executor, str) and executor not in ("thread", "process"):
+        raise ValueError(
+            f"executor must be 'thread', 'process', an Executor, or "
+            f"None — got {executor!r}"
+        )
+    if tpu and (
+        executor == "process" or isinstance(executor, ProcessPoolExecutor)
+    ):
+        raise ValueError(
+            "a process executor cannot run on a TPU host: the chip belongs "
+            "to one process, and spawn workers import JAX — use "
+            "executor='thread' or None"
+        )
+    if executor == "thread" and n_shards > 1 and not tpu:
+        warnings.warn(
+            _THREAD_FOOTGUN.format(k=n_shards),
+            PerformanceWarning,
+            stacklevel=stacklevel,
+        )
     return executor
 
 
@@ -889,8 +902,8 @@ def sharded_ingest(
     (typically with ``tighten=False``: route here, publish there).
 
     ``executor`` selects the pool.  ``None`` resolves via
-    :func:`resolve_executor`: ``"process"`` for ``n_shards >= 2``,
-    ``"thread"`` for one shard.  ``"process"`` takes the multi-host
+    :func:`resolve_executor`: ``"process"`` for ``n_shards >= 2`` off the
+    TPU, ``"thread"`` otherwise.  ``"process"`` takes the multi-host
     shape — spawn-context workers in the RESIDENT module pool
     (:func:`process_pool`) run against a :class:`ProcessShardSession`
     replica (shipped at most once per worker — pass ``session=`` to
@@ -921,7 +934,7 @@ def sharded_ingest(
             raise ValueError(
                 "session does not match this run's tree/shards/batch/fused"
             )
-        executor = "process"
+        executor = resolve_executor("process", n_shards)
     else:
         executor = resolve_executor(executor, n_shards)
     if buffers is not None:

@@ -41,8 +41,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.route_records import HIGHEST, eval_cut_tile
+
 # f32-exact sentinel beyond any dictionary code (codes < 2**24)
 BIG = float(2**25)
+
+# Largest padded leaf count whose full-array accumulators fit the 16 MB of
+# scoped VMEM on a TPU v5e at the TPC-H widths (D=21, 256 categorical
+# bits): 8192 leaves need 8 MB for the categorical accumulator alone.
+# Tiling the accumulators over the leaf axis would lift the ceiling.
+MAX_LEAF_BUCKET = 4096
 
 
 def _fused_ingest_kernel(  # qdlint: jit-body
@@ -92,62 +100,20 @@ def _fused_ingest_kernel(  # qdlint: jit-body
     @pl.when(j == 0)
     def _eval_cuts_once_per_record_tile():
         bids_ref[...] = jnp.zeros_like(bids_ref)
-        records = records_ref[...]  # (TM, D)
-        tm, d_total = records.shape
-
-        # range cuts: one-hot column select (MXU) + compare
-        vals = jnp.dot(
-            records, dim_onehot_ref[...], preferred_element_type=jnp.float32
-        )  # (TM, C)
-        rng = (vals < cutpoint_ref[...]).astype(jnp.float32)
-
-        # IN cuts: global categorical one-hot × membership masks
-        bit_iota = jax.lax.broadcasted_iota(
-            jnp.float32, (tm, n_cat_bits), 1
+        m, go, adv_res = eval_cut_tile(
+            records_ref[...],
+            dim_onehot_ref[...],
+            cutpoint_ref[...],
+            in_mask_ref[...],
+            is_cat_ref[...],
+            cat_off_ref[...],
+            adv_cols_ref[...],
+            adv_sel_ref[...],
+            kind_ref[...],
+            n_adv=n_adv,
+            n_cat_bits=n_cat_bits,
         )
-        bitpos = records + cat_off_ref[...]
-        is_cat = is_cat_ref[...]
-        go = jnp.zeros((tm, n_cat_bits), jnp.float32)
-        for d in range(d_total):  # static loop over table columns
-            hit_d = (bit_iota == bitpos[:, d][:, None]).astype(jnp.float32)
-            go = go + hit_d * is_cat[0, d]
-        inm = jnp.dot(
-            go, in_mask_ref[...], preferred_element_type=jnp.float32
-        )
-        inm = (inm > 0.5).astype(jnp.float32)
-
-        # advanced cuts: static small loop over binary predicates
-        c = vals.shape[1]
-        advm = jnp.zeros((tm, c), jnp.float32)
-        adv_res = jnp.zeros((tm, adv_sel_ref.shape[0]), jnp.float32)
-        if n_adv > 0:
-            for a in range(n_adv):
-                col_a = adv_cols_ref[a, 0]
-                op = adv_cols_ref[a, 1]
-                col_b = adv_cols_ref[a, 2]
-                d_iota = jax.lax.broadcasted_iota(
-                    jnp.float32, (tm, d_total), 1
-                )
-                va = jnp.sum(
-                    records * (d_iota == col_a).astype(jnp.float32), axis=1
-                )
-                vb = jnp.sum(
-                    records * (d_iota == col_b).astype(jnp.float32), axis=1
-                )
-                t = jnp.select(
-                    [op == 0, op == 1, op == 2, op == 3, op == 4],
-                    [va < vb, va <= vb, va > vb, va >= vb, va == vb],
-                    va != vb,
-                ).astype(jnp.float32)
-                adv_res = adv_res.at[:, a].set(t)
-            advm = jnp.dot(
-                adv_res, adv_sel_ref[...], preferred_element_type=jnp.float32
-            )
-
-        kind = kind_ref[...]
-        m_scratch[...] = jnp.where(
-            kind == 0.0, rng, jnp.where(kind == 1.0, inm, advm)
-        )
+        m_scratch[...] = m
         go_scratch[...] = go
         adv_scratch[...] = adv_res
 
@@ -160,7 +126,8 @@ def _fused_ingest_kernel(  # qdlint: jit-body
     # bids: identical to locate_leaf_pallas (padding rows included; the
     # host slices them off) — accumulated across leaf tiles
     bids_ref[...] += jnp.dot(
-        hit, leafid_ref[...].T, preferred_element_type=jnp.float32
+        hit, leafid_ref[...].T, preferred_element_type=jnp.float32,
+        precision=HIGHEST,
     )
 
     # -- per-leaf tightening partials (valid rows only) ---------------------

@@ -91,19 +91,20 @@ def query_intersect(
     advf = tree.leaf_adv[:, :, 1] if n_adv else np.zeros((L, 1), bool)
     leaf_advt = _pad_to(advt.astype(np.float32), 0, tile_l)
     leaf_advf = _pad_to(advf.astype(np.float32), 0, tile_l)
-    leaf_size = _pad_to(sizes[:, None], 0, tile_l)
+    leaf_size = _pad_to(sizes[None, :], 1, tile_l)
 
+    # conjunct operands go to the kernel conjunct-major: (width, n_conj)
     nc = wt.n_conjuncts
-    q_lo = _pad_to(wt.q_lo.astype(np.float32), 0, tile_c)
-    q_hi = _pad_to(wt.q_hi.astype(np.float32), 0, tile_c, fill=0)
-    q_cat = _pad_to(wt.q_cat.astype(np.float32), 0, tile_c)
+    q_lo = _pad_to(wt.q_lo.T.astype(np.float32), 1, tile_c)
+    q_hi = _pad_to(wt.q_hi.T.astype(np.float32), 1, tile_c, fill=0)
+    q_cat = _pad_to(wt.q_cat.T.astype(np.float32), 1, tile_c)
     reqt = (wt.q_adv == qry.ADV_TRUE).astype(np.float32)
     reqf = (wt.q_adv == qry.ADV_FALSE).astype(np.float32)
     if reqt.shape[1] < a3:
         reqt = np.pad(reqt, ((0, 0), (0, a3 - reqt.shape[1])))
         reqf = np.pad(reqf, ((0, 0), (0, a3 - reqf.shape[1])))
-    q_reqt = _pad_to(reqt, 0, tile_c)
-    q_reqf = _pad_to(reqf, 0, tile_c)
+    q_reqt = _pad_to(reqt.T, 1, tile_c)
+    q_reqf = _pad_to(reqf.T, 1, tile_c)
 
     numeric_dims = tuple(
         int(i) for i in np.nonzero(~schema.is_categorical)[0]

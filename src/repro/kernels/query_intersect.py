@@ -12,6 +12,12 @@ bit segment (MXU); advanced-cut polarity checks are a small static loop.
 
 Grid = (n_conj_tiles, n_leaf_tiles) with the *leaf* axis innermost so the
 ``scanned`` accumulator block (0, c) is revisited in consecutive grid steps.
+
+Block operands are block-major (one row per block) and conjunct operands
+conjunct-major (one column per conjunct), so every test slices 2-D columns
+and rows and sums failures into one (TL, TC) count.  Slicing 1-D vectors
+and reshaping them instead kept one relayout per dim alive and overflowed
+the 16 MB of scoped VMEM on a TPU v5e at 128 × 128 tiles.
 """
 
 from __future__ import annotations
@@ -31,12 +37,12 @@ def _intersect_kernel(  # qdlint: jit-body
     leaf_cat_ref,  # (TL, B) f32
     leaf_advt_ref,  # (TL, A) f32 — may contain satisfying records
     leaf_advf_ref,  # (TL, A) f32 — may contain violating records
-    leaf_size_ref,  # (TL, 1) f32
-    q_lo_ref,  # (TC, D) f32
-    q_hi_ref,  # (TC, D) f32
-    q_cat_ref,  # (TC, B) f32
-    q_reqt_ref,  # (TC, A) f32 — conjunct requires pred true
-    q_reqf_ref,  # (TC, A) f32 — conjunct requires pred false
+    leaf_size_ref,  # (1, TL) f32
+    q_lo_ref,  # (D, TC) f32 — conjunct-major: one column per conjunct
+    q_hi_ref,  # (D, TC) f32
+    q_cat_ref,  # (B, TC) f32
+    q_reqt_ref,  # (A, TC) f32 — conjunct requires pred true
+    q_reqf_ref,  # (A, TC) f32 — conjunct requires pred false
     hits_ref,  # out (TL, TC) f32
     scanned_ref,  # out (1, TC) f32, accumulated over leaf tiles
     *,
@@ -50,37 +56,41 @@ def _intersect_kernel(  # qdlint: jit-body
     def _init():
         scanned_ref[...] = jnp.zeros_like(scanned_ref)
 
-    tl = leaf_lo_ref.shape[0]
-    tc = q_lo_ref.shape[0]
-    ok = jnp.ones((tl, tc), jnp.float32)
+    leaf_lo, leaf_hi = leaf_lo_ref[...], leaf_hi_ref[...]
+    q_lo, q_hi = q_lo_ref[...], q_hi_ref[...]
+    # every test below counts one failed requirement per (block, conjunct);
+    # a (TL, 1) leaf column meets a (1, TC) conjunct row, so each operand
+    # broadcasts along one axis only
+    miss = jnp.zeros((leaf_lo.shape[0], q_lo.shape[1]), jnp.float32)
 
     # numeric box overlap: max(lo) < min(hi), per dim (static unroll)
     for d in numeric_dims:
-        lo = jnp.maximum(leaf_lo_ref[:, d][:, None], q_lo_ref[:, d][None, :])
-        hi = jnp.minimum(leaf_hi_ref[:, d][:, None], q_hi_ref[:, d][None, :])
-        ok = ok * (lo < hi).astype(jnp.float32)
+        lo = jnp.maximum(leaf_lo[:, d : d + 1], q_lo[d : d + 1, :])
+        hi = jnp.minimum(leaf_hi[:, d : d + 1], q_hi[d : d + 1, :])
+        miss = miss + (lo >= hi).astype(jnp.float32)
 
     # categorical: each dim must share ≥1 allowed value (mask matmul per dim)
+    leaf_cat, q_cat = leaf_cat_ref[...], q_cat_ref[...]
     for (s, e) in cat_segments:
         shared = jnp.dot(
-            leaf_cat_ref[:, s:e],
-            q_cat_ref[:, s:e].T,
+            leaf_cat[:, s:e], q_cat[s:e, :],
             preferred_element_type=jnp.float32,
         )
-        ok = ok * (shared > 0.5).astype(jnp.float32)
+        miss = miss + (shared < 0.5).astype(jnp.float32)
 
     # advanced-cut polarity compatibility
+    may_t, may_f = leaf_advt_ref[...], leaf_advf_ref[...]
+    req_t, req_f = q_reqt_ref[...], q_reqf_ref[...]
     for a in range(n_adv):
-        may_t = leaf_advt_ref[:, a][:, None]
-        may_f = leaf_advf_ref[:, a][:, None]
-        req_t = q_reqt_ref[:, a][None, :]
-        req_f = q_reqf_ref[:, a][None, :]
-        ok = ok * (1.0 - req_t * (1.0 - may_t))
-        ok = ok * (1.0 - req_f * (1.0 - may_f))
+        miss = miss + req_t[a : a + 1, :] * (1.0 - may_t[:, a : a + 1])
+        miss = miss + req_f[a : a + 1, :] * (1.0 - may_f[:, a : a + 1])
 
+    ok = (miss < 0.5).astype(jnp.float32)
     hits_ref[...] = ok
+    # block sizes reach the millions: keep the f32 matmul exact
     scanned_ref[...] += jnp.dot(
-        leaf_size_ref[...].T, ok, preferred_element_type=jnp.float32
+        leaf_size_ref[...], ok, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -103,7 +113,7 @@ def query_intersect_pallas(
     interpret: bool,
 ):
     l, d = leaf_lo.shape
-    c = q_lo.shape[0]
+    c = q_lo.shape[1]
     b = leaf_cat.shape[1]
     a = leaf_advt.shape[1]
     grid = (c // tile_c, l // tile_l)  # leaf axis innermost (accumulator)
@@ -117,7 +127,7 @@ def query_intersect_pallas(
         (tile_l, width), lambda j, i: (i, 0)
     )
     conj_spec = lambda width: pl.BlockSpec(
-        (tile_c, width), lambda j, i: (j, 0)
+        (width, tile_c), lambda j, i: (0, j)
     )
     hits, scanned = pl.pallas_call(
         kernel,
@@ -128,7 +138,7 @@ def query_intersect_pallas(
             leaf_spec(b),  # leaf_cat
             leaf_spec(a),  # leaf_advt
             leaf_spec(a),  # leaf_advf
-            leaf_spec(1),  # leaf_size
+            pl.BlockSpec((1, tile_l), lambda j, i: (0, i)),  # leaf_size
             conj_spec(d),  # q_lo
             conj_spec(d),  # q_hi
             conj_spec(b),  # q_cat

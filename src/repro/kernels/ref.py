@@ -1,79 +1,18 @@
-"""Pure-jnp oracles for the Pallas kernels (same inputs, no tiling).
+"""Numpy oracles for the fused ingestion path.
 
-These are the correctness references the kernel tests sweep against; the
-end-to-end semantic oracle is ``FrozenQdTree.route`` / ``query.
-conjuncts_intersect`` (numpy), which ``ops.py`` wires up identically.
-
-``fused_ingest_ref`` is the *numpy* bit-identity oracle for the fused
-single-pass ingestion path: route via the numpy descent, tighten via the
-legacy ``IncrementalTightener`` arithmetic, packaged as the same
+The kernel tests compare every backend against the numpy semantics:
+``FrozenQdTree.route`` / ``query.conjuncts_intersect`` for routing and
+intersection, and here ``fused_ingest_ref`` for the fused single-pass
+ingestion path: route via the numpy descent, tighten via the legacy
+``IncrementalTightener`` arithmetic, packaged as the same
 ``(bids, TightenPartial)`` pair every fused backend returns.
-``fused_ingest_ops_ref`` mirrors the Pallas kernel at the padded-operand
-level (same inputs and f32 outputs, no tiling).
+``partial_from_fused`` converts a device backend's f32 aggregates into
+that exchange format.
 """
 
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
-
-
-def eval_cuts_ref(
-    records_f32,  # (M, D)
-    dim_onehot,  # (D, C)
-    cutpoint,  # (1, C)
-    in_mask_t,  # (B, C)
-    is_cat_row,  # (1, D)
-    cat_offset_row,  # (1, D)
-    adv_cols,  # (A3, 3)
-    adv_sel,  # (A3, C)
-    kind_row,  # (1, C)
-    n_adv: int,
-):
-    m, d = records_f32.shape
-    vals = records_f32 @ dim_onehot
-    rng = (vals < cutpoint).astype(jnp.float32)
-
-    bits = in_mask_t.shape[0]
-    bitpos = records_f32 + cat_offset_row  # (M, D)
-    onehots = (
-        bitpos[:, :, None] == jnp.arange(bits, dtype=jnp.float32)
-    ).astype(jnp.float32)
-    go = (onehots * is_cat_row[0][None, :, None]).sum(axis=1)  # (M, B)
-    inm = ((go @ in_mask_t) > 0.5).astype(jnp.float32)
-
-    c = vals.shape[1]
-    advm = jnp.zeros((m, c), jnp.float32)
-    if n_adv > 0:
-        res = []
-        for j in range(n_adv):
-            ca, op, cb = adv_cols[j, 0], adv_cols[j, 1], adv_cols[j, 2]
-            didx = jnp.arange(d, dtype=jnp.float32)
-            va = (records_f32 * (didx == ca)).sum(axis=1)
-            vb = (records_f32 * (didx == cb)).sum(axis=1)
-            t = jnp.select(
-                [op == 0, op == 1, op == 2, op == 3, op == 4],
-                [va < vb, va <= vb, va > vb, va >= vb, va == vb],
-                va != vb,
-            )
-            res.append(t.astype(jnp.float32))
-        pad = adv_sel.shape[0] - n_adv
-        adv_res = jnp.stack(res, axis=1)
-        if pad:
-            adv_res = jnp.concatenate(
-                [adv_res, jnp.zeros((m, pad), jnp.float32)], axis=1
-            )
-        advm = adv_res @ adv_sel
-
-    return jnp.where(
-        kind_row == 0.0, rng, jnp.where(kind_row == 1.0, inm, advm)
-    )
-
-
-def locate_leaf_ref(m_mat, pathpos, pathneg, leafid):
-    viol = (1.0 - m_mat) @ pathpos + m_mat @ pathneg
-    hit = (viol < 0.5).astype(jnp.float32)
-    return hit @ leafid[0] - 1.0
 
 
 def fused_ingest_ref(tree, records):
@@ -90,72 +29,6 @@ def fused_ingest_ref(tree, records):
     t = IncrementalTightener(tree)
     t.update(records, bids)
     return bids, t.as_partial()
-
-
-def fused_ingest_ops_ref(
-    records_f32,  # (M, D)
-    valid,  # (M, 1)
-    dim_onehot, cutpoint, in_mask_t, is_cat_row, cat_offset_row,
-    adv_cols, adv_sel, kind_row,
-    pathpos,  # (C, L)
-    pathneg,  # (C, L)
-    leafid,  # (1, L)
-    n_adv: int,
-    big: float = float(2**25),
-):
-    """Operand-level oracle for ``fused_ingest_pallas`` (same outputs)."""
-    m_mat = eval_cuts_ref(
-        records_f32, dim_onehot, cutpoint, in_mask_t, is_cat_row,
-        cat_offset_row, adv_cols, adv_sel, kind_row, n_adv,
-    )
-    viol = (1.0 - m_mat) @ pathpos + m_mat @ pathneg
-    hit = (viol < 0.5).astype(jnp.float32)  # (M, L)
-    bids = hit @ leafid.T  # (M, 1), bid + 1
-    hitv = hit * valid
-    counts = hitv.sum(axis=0, keepdims=True)  # (1, L)
-
-    d = records_f32.shape[1]
-    lo = jnp.stack(
-        [
-            jnp.where(hitv > 0.5, records_f32[:, dd][:, None], big).min(0)
-            for dd in range(d)
-        ],
-        axis=1,
-    )
-    hi = jnp.stack(
-        [
-            jnp.where(hitv > 0.5, records_f32[:, dd][:, None], -big).max(0)
-            for dd in range(d)
-        ],
-        axis=1,
-    )
-
-    bits = in_mask_t.shape[0]
-    bitpos = records_f32 + cat_offset_row
-    onehots = (
-        bitpos[:, :, None] == jnp.arange(bits, dtype=jnp.float32)
-    ).astype(jnp.float32)
-    go = (onehots * is_cat_row[0][None, :, None]).sum(axis=1)  # (M, B)
-    cat = ((hitv.T @ go) > 0.5).astype(jnp.float32)  # (L, B)
-
-    a3 = adv_sel.shape[0]
-    adv_res = jnp.zeros((records_f32.shape[0], a3), jnp.float32)
-    if n_adv > 0:
-        didx = jnp.arange(d, dtype=jnp.float32)
-        for a in range(n_adv):
-            ca, op, cb = adv_cols[a, 0], adv_cols[a, 1], adv_cols[a, 2]
-            va = (records_f32 * (didx == ca)).sum(axis=1)
-            vb = (records_f32 * (didx == cb)).sum(axis=1)
-            t = jnp.select(
-                [op == 0, op == 1, op == 2, op == 3, op == 4],
-                [va < vb, va <= vb, va > vb, va >= vb, va == vb],
-                va != vb,
-            )
-            adv_res = adv_res.at[:, a].set(t.astype(jnp.float32))
-    advtp = hitv.T @ adv_res  # (L, A3)
-    advt = (advtp > 0.5).astype(jnp.float32)
-    advf = ((counts[0][:, None] - advtp) > 0.5).astype(jnp.float32)
-    return bids, counts, lo, hi, cat, advt, advf
 
 
 def partial_from_fused(tree, counts, lo, hi, cat, advt, advf):
@@ -191,28 +64,3 @@ def partial_from_fused(tree, counts, lo, hi, cat, advt, advf):
     return TightenPartial(
         counts=counts, lo=lo64, hi=hi64, cat=pcat, adv=padv
     )
-
-
-def query_intersect_ref(
-    leaf_lo, leaf_hi, leaf_cat, leaf_advt, leaf_advf, leaf_size,
-    q_lo, q_hi, q_cat, q_reqt, q_reqf,
-    numeric_dims, cat_segments, n_adv,
-):
-    tl, tc = leaf_lo.shape[0], q_lo.shape[0]
-    ok = jnp.ones((tl, tc), jnp.float32)
-    for d in numeric_dims:
-        lo = jnp.maximum(leaf_lo[:, d][:, None], q_lo[:, d][None, :])
-        hi = jnp.minimum(leaf_hi[:, d][:, None], q_hi[:, d][None, :])
-        ok = ok * (lo < hi).astype(jnp.float32)
-    for (s, e) in cat_segments:
-        shared = leaf_cat[:, s:e] @ q_cat[:, s:e].T
-        ok = ok * (shared > 0.5).astype(jnp.float32)
-    for a in range(n_adv):
-        ok = ok * (
-            1.0 - q_reqt[:, a][None, :] * (1.0 - leaf_advt[:, a][:, None])
-        )
-        ok = ok * (
-            1.0 - q_reqf[:, a][None, :] * (1.0 - leaf_advf[:, a][:, None])
-        )
-    scanned = leaf_size.T @ ok
-    return ok, scanned

@@ -18,7 +18,13 @@ the tree.  On TPU we factorize routing into two dense, gather-free kernels:
      zero-violation leaf is recovered with a weighted mask reduction.
 
 All integer data is dictionary-encoded and must satisfy dom < 2**24 so
-float32 MXU arithmetic is exact.
+float32 MXU arithmetic is exact.  Dots whose operands carry values above
+256 (dictionary codes, leaf ids) ask for ``Precision.HIGHEST``: Mosaic's
+default f32 matmul may round operands to bf16.
+
+Mosaic lowers f32 selects and compares, integer iota, and neither scatters
+nor selects over bool vectors, so every mask in the kernel bodies is an
+f32 0/1 array and every iota is int32 cast to f32.
 """
 
 from __future__ import annotations
@@ -31,81 +37,135 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def f32_iota(shape: tuple[int, int], axis: int) -> jnp.ndarray:
+    """Integer iota cast to f32 (Mosaic has no float iota)."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis).astype(
+        jnp.float32
+    )
+
+
+def eval_cut_tile(
+    records,  # (TM, D) f32 — record tile (dictionary codes)
+    dim_onehot,  # (D, C) f32 — one-hot of each cut's column
+    cutpoint,  # (1, C) f32
+    in_mask_t,  # (B, C) f32 — transposed IN membership masks
+    is_cat,  # (1, D) f32 — 1.0 where dim is categorical
+    cat_off,  # (1, D) f32 — bit-space offset per dim (0 for numeric)
+    adv_cols,  # (A3, 3) f32 — rows: (col_a, op, col_b); A3 = max(n_adv,1)
+    adv_sel,  # (A3, C) f32 — one-hot map adv id -> cut column
+    kind,  # (1, C) f32 — cut kind per column: 0 range, 1 in, 2 adv
+    *,
+    n_adv: int,
+    n_cat_bits: int,
+):
+    """Predicate matrix of one record tile, shared by both routing kernels.
+
+    Returns ``(m, go, adv_res)``: the (TM, C) 0/1 predicate matrix, the
+    (TM, B) global categorical one-hot and the (TM, A3) advanced-predicate
+    truth bits.
+    """
+    tm, d_total = records.shape
+
+    # -- range cuts: select each cut's column, compare against cutpoint ----
+    vals = jnp.dot(
+        records, dim_onehot, preferred_element_type=jnp.float32,
+        precision=HIGHEST,
+    )  # (TM, C)
+    rng = (vals < cutpoint).astype(jnp.float32)
+
+    # -- IN cuts: global categorical one-hot  ×  membership masks ----------
+    # GO[r, b] = 1 iff some categorical dim d has records[r, d] + off_d == b.
+    # in_mask rows are zero outside their own dim segment, so the cross-dim
+    # bits never contribute to the product.
+    bit_iota = f32_iota((tm, n_cat_bits), 1)
+    bitpos = records + cat_off  # (TM, D); junk for numeric dims
+    go = jnp.zeros((tm, n_cat_bits), jnp.float32)
+    for d in range(d_total):  # static loop over table columns
+        hit = (bit_iota == bitpos[:, d : d + 1]).astype(jnp.float32)
+        go = go + hit * is_cat[:, d : d + 1]
+    inm = jnp.dot(go, in_mask_t, preferred_element_type=jnp.float32)
+    inm = (inm > 0.5).astype(jnp.float32)
+
+    # -- advanced cuts: static small loop over binary predicates -----------
+    a3 = adv_sel.shape[0]
+    adv_res = jnp.zeros((tm, a3), jnp.float32)
+    advm = jnp.zeros_like(vals)
+    if n_adv > 0:
+        d_iota = f32_iota((1, d_total), 1)
+        a_iota = f32_iota((1, a3), 1)
+        for j in range(n_adv):  # n_adv is small and static (paper Sec 6.1)
+            row = adv_cols[j : j + 1, :]  # (1, 3)
+            col_a, op, col_b = row[:, 0:1], row[:, 1:2], row[:, 2:3]
+            # one-hot select the two columns (dynamic col id, static loop
+            # j); the (1, D) masks broadcast over sublanes only
+            va = jnp.sum(
+                records * (d_iota == col_a).astype(jnp.float32),
+                axis=1, keepdims=True,
+            )
+            vb = jnp.sum(
+                records * (d_iota == col_b).astype(jnp.float32),
+                axis=1, keepdims=True,
+            )
+            lt = (va < vb).astype(jnp.float32)  # (TM, 1)
+            eq = (va == vb).astype(jnp.float32)
+            gt = 1.0 - lt - eq
+
+            def is_op(k):  # (1, 1) 0/1 weight of comparison op k
+                return (op == float(k)).astype(jnp.float32)
+
+            # ops 0..4 are < <= > >= ==; anything else is != (predicates.py)
+            t = (
+                is_op(0) * lt
+                + is_op(1) * (lt + eq)
+                + is_op(2) * gt
+                + is_op(3) * (gt + eq)
+                + is_op(4) * eq
+                + (op > 4.5).astype(jnp.float32) * (1.0 - eq)
+            )
+            adv_res = adv_res + t * (a_iota == float(j)).astype(jnp.float32)
+        advm = jnp.dot(adv_res, adv_sel, preferred_element_type=jnp.float32)
+
+    m = jnp.where(kind == 0.0, rng, jnp.where(kind == 1.0, inm, advm))
+    return m, go, adv_res
+
 
 # ---------------------------------------------------------------------------
 # Kernel 1: predicate-matrix evaluation
 # ---------------------------------------------------------------------------
 def _eval_cuts_kernel(  # qdlint: jit-body
-    # inputs (VMEM refs)
-    records_ref,  # (TM, D) f32 — record tile (dictionary codes)
-    dim_onehot_ref,  # (D, C) f32 — one-hot of each cut's column
-    cutpoint_ref,  # (1, C) f32
-    in_mask_ref,  # (B, C) f32 — transposed IN membership masks
-    cat_onehot_dims_ref,  # (1, D) f32 — 1.0 where dim is categorical
-    cat_offset_ref,  # (1, D) f32 — bit-space offset per dim (0 for numeric)
-    adv_cols_ref,  # (A3, 3) f32 — rows: (col_a, op, col_b); A3 = max(n_adv,1)
-    adv_sel_ref,  # (A3, C) f32 — one-hot map adv id -> cut column
-    kind_ref,  # (1, C) f32 — cut kind per column
+    # inputs (VMEM refs; shapes as in eval_cut_tile)
+    records_ref,
+    dim_onehot_ref,
+    cutpoint_ref,
+    in_mask_ref,
+    cat_onehot_dims_ref,
+    cat_offset_ref,
+    adv_cols_ref,
+    adv_sel_ref,
+    kind_ref,
     # outputs
     m_ref,  # (TM, C) f32 — predicate matrix tile (0.0 / 1.0)
     *,
     n_adv: int,
     n_cat_bits: int,
 ):
-    records = records_ref[...]  # (TM, D)
-    tm = records.shape[0]
-
-    # -- range cuts: select each cut's column, compare against cutpoint ----
-    vals = jnp.dot(
-        records, dim_onehot_ref[...], preferred_element_type=jnp.float32
-    )  # (TM, C)
-    rng = (vals < cutpoint_ref[...]).astype(jnp.float32)
-
-    # -- IN cuts: global categorical one-hot  ×  membership masks ----------
-    # GO[r, b] = 1 iff some categorical dim d has records[r, d] + off_d == b.
-    # in_mask rows are zero outside their own dim segment, so the cross-dim
-    # bits never contribute to the product.
-    bit_iota = jax.lax.broadcasted_iota(jnp.float32, (tm, n_cat_bits), 1)
-    bitpos = records + cat_offset_ref[...]  # (TM, D); junk for numeric dims
-    is_cat = cat_onehot_dims_ref[...]  # (1, D)
-    go = jnp.zeros((tm, n_cat_bits), jnp.float32)
-    d_total = records.shape[1]
-    for d in range(d_total):  # static loop over table columns
-        hit = (bit_iota == bitpos[:, d][:, None]).astype(jnp.float32)
-        go = go + hit * is_cat[0, d]
-    inm = jnp.dot(go, in_mask_ref[...], preferred_element_type=jnp.float32)
-    inm = (inm > 0.5).astype(jnp.float32)
-
-    # -- advanced cuts: static small loop over binary predicates -----------
-    c = vals.shape[1]
-    advm = jnp.zeros((tm, c), jnp.float32)
-    if n_adv > 0:
-        adv_res = jnp.zeros((tm, adv_sel_ref.shape[0]), jnp.float32)
-        for j in range(n_adv):  # n_adv is small and static (paper Sec 6.1)
-            col_a = adv_cols_ref[j, 0]
-            op = adv_cols_ref[j, 1]
-            col_b = adv_cols_ref[j, 2]
-            # one-hot select the two columns (dynamic col id, static loop j)
-            d_iota = jax.lax.broadcasted_iota(jnp.float32, (tm, d_total), 1)
-            va = jnp.sum(
-                records * (d_iota == col_a).astype(jnp.float32), axis=1
-            )
-            vb = jnp.sum(
-                records * (d_iota == col_b).astype(jnp.float32), axis=1
-            )
-            t = jnp.select(
-                [op == 0, op == 1, op == 2, op == 3, op == 4],
-                [va < vb, va <= vb, va > vb, va >= vb, va == vb],
-                va != vb,
-            ).astype(jnp.float32)
-            adv_res = adv_res.at[:, j].set(t)
-        advm = jnp.dot(
-            adv_res, adv_sel_ref[...], preferred_element_type=jnp.float32
-        )
-
-    kind = kind_ref[...]  # (1, C): 0 range, 1 in, 2 adv
-    out = jnp.where(kind == 0.0, rng, jnp.where(kind == 1.0, inm, advm))
-    m_ref[...] = out
+    m, _, _ = eval_cut_tile(
+        records_ref[...],
+        dim_onehot_ref[...],
+        cutpoint_ref[...],
+        in_mask_ref[...],
+        cat_onehot_dims_ref[...],
+        cat_offset_ref[...],
+        adv_cols_ref[...],
+        adv_sel_ref[...],
+        kind_ref[...],
+        n_adv=n_adv,
+        n_cat_bits=n_cat_bits,
+    )
+    m_ref[...] = m
 
 
 @functools.partial(
@@ -187,7 +247,8 @@ def _locate_leaf_kernel(  # qdlint: jit-body
     hit = (viol < 0.5).astype(jnp.float32)  # (TM, TL)
     # each record matches exactly one (unpadded) leaf across all L tiles
     partial = jnp.dot(
-        hit, leafid_ref[...].T, preferred_element_type=jnp.float32
+        hit, leafid_ref[...].T, preferred_element_type=jnp.float32,
+        precision=HIGHEST,
     )  # (TM, 1)
     out_ref[...] += partial
 

@@ -42,6 +42,7 @@ from repro.core import query as qry
 from repro.data import datagen, workload as wl
 from repro.data.blocks import BlockBuffers
 from repro.engine import pad_bucket, trace_counts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.service import (
     DriftConfig,
     IngestOptions,
@@ -150,7 +151,9 @@ def main() -> None:
                     help="shard executor (--shards > 1): 'process' routes "
                          "shards in resident spawn workers against a "
                          "shipped tree replica (the 'auto' default for "
-                         ">= 2 shards); 'thread' shares the live engine's "
+                         ">= 2 shards off the TPU; refused on a TPU host, "
+                         "whose chip belongs to one process); 'thread' "
+                         "shares the live engine's "
                          "compiled plans but also its GIL — measured "
                          "0.44x single-stream at k=8, so it warns")
     ap.add_argument("--coordinator", action="store_true",
@@ -197,6 +200,7 @@ def main() -> None:
                     help="optional path to persist the ingested BlockStore")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     schema, records, work, cuts = make_workload(
         args.workload, args.rows, args.seed

@@ -29,6 +29,7 @@ import numpy as np
 from repro.core import query as qry
 from repro.engine import trace_counts
 from repro.engine.plan import trace_delta
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.ingest import make_workload
 from repro.serve import AdmissionError, QueryServer, ServeConfig
 from repro.service import LayoutService
@@ -85,6 +86,7 @@ def main(argv=None) -> dict:
                          "workload clusters")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     schema, records, work, cuts = make_workload(
         args.workload, args.rows, args.seed

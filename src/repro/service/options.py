@@ -44,8 +44,10 @@ class IngestOptions:
     fused        single-pass route+tighten kernels (default) vs the
                  two-pass route-then-tighten path.
     executor     sharded runs: ``None`` picks ``"process"`` (resident
-                 spawn workers) for ``shards >= 2`` and ``"thread"``
-                 otherwise; ``"thread"`` with multiple shards carries a
+                 spawn workers) for ``shards >= 2`` off the TPU and
+                 ``"thread"`` otherwise; on a TPU host a process
+                 executor raises (one process per chip); ``"thread"``
+                 with multiple shards off the TPU carries a
                  documented PerformanceWarning (GIL-bound, measured
                  0.44x); any ``concurrent.futures`` Executor instance is
                  used as-is.

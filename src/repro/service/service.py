@@ -386,7 +386,7 @@ class LayoutService:
     def _ingest_sharded(self, records, n_shards, options, kw):
         """The sharded arm of :meth:`ingest` (record array, shards >= 2
         and/or a fleet coordinator)."""
-        from repro.engine.sharded import sharded_ingest
+        from repro.engine.sharded import resolve_executor, sharded_ingest
 
         live = self._live  # consistent engine/tree view for the whole run
         monitor = options.monitor
@@ -399,8 +399,10 @@ class LayoutService:
             if observed is not None and len(observed):
                 kw["observe"] = observed
         session = None
-        if options.executor == "process" or (
-            options.executor is None and n_shards >= 2
+        # an explicit "thread" never needs a session (and resolving it
+        # here would repeat sharded_ingest's PerformanceWarning)
+        if options.executor != "thread" and (
+            resolve_executor(options.executor, n_shards) == "process"
         ):
             session = self._shard_session(live, n_shards, options, kw)
         if coordinator is not None:

@@ -1,7 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps + allclose vs ref oracles.
 
-Kernels run in interpret mode on CPU (the container has no TPU); the same
-pl.pallas_call/BlockSpec code path compiles for TPU.
+Kernels run in interpret mode on the CPU; ``tests/test_tpu_compile.py``
+compiles the same pl.pallas_call/BlockSpec code for a TPU v5e.
 """
 
 import numpy as np

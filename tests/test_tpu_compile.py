@@ -1,0 +1,126 @@
+"""The main path's Pallas kernels compile for a TPU v5e.
+
+JAX's TPU compiler ships with jaxlib, so each test lowers one kernel with
+``interpret=False`` at the TPC-H widths and compiles it for one chip of a
+*described* ``v5e:2x2`` topology — no chip attached.  What Mosaic refuses
+(an op it cannot lower, more VMEM than a kernel may use) fails here, where
+interpret-mode tests cannot see it.
+
+Only one process at a time may load the TPU library, so the topology is
+described inside a fixture of this file and nowhere else (never at import,
+in ``conftest.py`` or in ``parametrize``), and the compiles run in the
+test's own process.
+"""
+
+from __future__ import annotations
+
+import inspect
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.data import datagen
+from repro.kernels import fused_ingest as fk
+from repro.kernels import ops
+from repro.kernels import query_intersect as qk
+from repro.kernels import route_records as rk
+
+D = 21  # TPC-H-like table width (datagen.tpch_like_schema)
+B = 256  # its 146 categorical bits, padded to a LANE multiple
+C = 256  # cut bucket of the TPC-H template workload's 212 candidate cuts
+A = 2  # advanced (column-vs-column) cuts
+M = 65_536  # records per ingest batch (chip_smoke.py's default)
+TILE_M = 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def f32(topo):
+    """Shape factory for f32 operands placed on one described chip.
+
+    The persistent compilation cache is off meanwhile: an entry compiled
+    for a described chip cannot be read back without one.
+    """
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    yield lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.float32, sharding=one_chip
+    )
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _assert_mosaic_kernel(lowered) -> None:
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("leaf_bucket", [128, 1024, fk.MAX_LEAF_BUCKET])
+def test_fused_ingest_compiles(f32, leaf_bucket):
+    _assert_mosaic_kernel(fk.fused_ingest_pallas.lower(
+        f32(M, D), f32(M, 1), f32(D, C), f32(1, C), f32(B, C), f32(1, D),
+        f32(1, D), f32(A, 3), f32(A, C), f32(1, C),
+        f32(C, leaf_bucket), f32(C, leaf_bucket), f32(1, leaf_bucket),
+        tile_m=TILE_M, tile_l=128, n_cat_bits=B, n_adv=A, interpret=False,
+    ))
+
+
+def test_eval_cuts_compiles(f32):
+    _assert_mosaic_kernel(rk.eval_cuts_pallas.lower(
+        f32(M, D), f32(D, C), f32(1, C), f32(B, C), f32(1, D), f32(1, D),
+        f32(A, 3), f32(A, C), f32(1, C),
+        tile_m=TILE_M, n_cat_bits=B, n_adv=A, interpret=False,
+    ))
+
+
+def test_locate_leaf_compiles(f32):
+    _assert_mosaic_kernel(rk.locate_leaf_pallas.lower(
+        f32(M, C), f32(C, 1024), f32(C, 1024), f32(1, 1024),
+        tile_m=TILE_M, tile_l=128, interpret=False,
+    ))
+
+
+def test_query_intersect_compiles_at_default_tiles(f32):
+    """The tiles ``ops.query_intersect`` uses unless told otherwise."""
+    params = inspect.signature(ops.query_intersect).parameters
+    tile_l, tile_c = params["tile_l"].default, params["tile_c"].default
+    schema = datagen.tpch_like_schema()
+    off = schema.cat_offsets
+    bits = schema.total_cat_bits
+    n_leaf, n_conj = 1024, 128
+    _assert_mosaic_kernel(qk.query_intersect_pallas.lower(
+        f32(n_leaf, D), f32(n_leaf, D), f32(n_leaf, bits), f32(n_leaf, A),
+        f32(n_leaf, A), f32(1, n_leaf),
+        f32(D, n_conj), f32(D, n_conj), f32(bits, n_conj), f32(A, n_conj),
+        f32(A, n_conj),
+        tile_l=tile_l,
+        tile_c=tile_c,
+        numeric_dims=tuple(
+            int(d) for d in np.nonzero(~schema.is_categorical)[0]
+        ),
+        cat_segments=tuple(
+            (int(off[d]), int(off[d]) + schema.columns[d].dom)
+            for d in np.nonzero(schema.is_categorical)[0]
+        ),
+        n_adv=A,
+        interpret=False,
+    ))
