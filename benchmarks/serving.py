@@ -18,8 +18,8 @@ records them in ``BENCH_serving.json``:
 **Phase 2 — closed loop (timings, never pinned).**  N client threads
 submit through the async dispatcher (admission → coalesce → cache →
 engine) while the main thread hot-swaps the layout under live traffic;
-reports achieved qps and p50/p99 latency, and re-asserts zero staleness
-and bit-identity under concurrency.
+reports achieved qps and the mean queue wait (submission to dispatch),
+and re-asserts zero staleness and bit-identity under concurrency.
 
     PYTHONPATH=src python -m benchmarks.serving            # bench scale
     PYTHONPATH=src python -m benchmarks.serving --smoke    # CI tiny
@@ -212,8 +212,7 @@ def run(smoke: bool = False, backend: str = "jax", seed: int = 0) -> dict:
     print(
         f"[serving] phase 2: {len(cl_pairs)} queries from {clients} "
         f"closed-loop clients in {wall:.2f}s -> {qps:,.0f} qps, "
-        f"p50 {cl['latency']['p50_ms']:.2f}ms "
-        f"p99 {cl['latency']['p99_ms']:.2f}ms, hit rate "
+        f"mean queue wait {cl['queue_wait_ms']:.3f}ms, hit rate "
         f"{cl['cache']['hit_rate']:.3f}, swap under traffic -> gen "
         f"{live_swap_gen}, stale {cl['counters']['stale_responses']}, "
         f"bit-identical {cl_bit_identical}"
@@ -261,8 +260,7 @@ def run(smoke: bool = False, backend: str = "jax", seed: int = 0) -> dict:
             "per_client": per_client,
             "queries": len(cl_pairs),
             "qps": qps,
-            "p50_ms": cl["latency"]["p50_ms"],
-            "p99_ms": cl["latency"]["p99_ms"],
+            "queue_wait_ms": cl["queue_wait_ms"],
             "hit_rate": cl["cache"]["hit_rate"],
             "stale_responses": cl["counters"]["stale_responses"],
             "swap_generation": live_swap_gen,

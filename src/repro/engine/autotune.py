@@ -13,7 +13,7 @@ mode (CPU/GPU dev boxes).  This module owns that decision:
   compile as a ``failed`` row — it is never retried on a slower path —
   then persists the fastest valid config with the mode it ran in.
 * :func:`lookup` / :func:`record` read/write the persisted store, keyed by
-  ``(backend, geometry-bucket)``; ``PallasBackend.fused_ingest`` consults
+  ``(backend, geometry-bucket)``; ``PallasBackend.fused_outputs`` consults
   it when the caller does not pin tiles explicitly.
 
 The store is a plain JSON file (``results/autotune_tiles.json`` by

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import query as qry
 from repro.core.qdtree import FrozenQdTree
 from repro.engine import plan as planlib
@@ -86,7 +87,7 @@ class Backend:
     ) -> np.ndarray:
         raise NotImplementedError
 
-    def fused_ingest(
+    def fused_outputs(
         self,
         tree: FrozenQdTree,
         cache: PlanCache,
@@ -94,13 +95,14 @@ class Backend:
         return_bids: bool = True,
         **opts,
     ):
-        """One single-pass route + tighten step.
+        """Phase one of a single-pass route + tighten step: the batch
+        staged, run, and its outputs pulled to the host.
 
-        Returns ``(bids int32 (m,), TightenPartial)`` — the per-leaf
-        tightening aggregates of this batch, bit-identical to routing
-        followed by ``IncrementalTightener.update``.  The base
-        implementation is the legacy two-pass fallback, so every backend
-        has a fused entry point even before it grows a fused kernel.
+        Returns ``(bids int32 (m,), outputs)``; :meth:`fold_outputs` turns
+        ``outputs`` into the batch's ``TightenPartial``.  The base
+        implementation is the legacy two-pass fallback, whose outputs
+        already are the partial, so every backend has a fused entry point
+        even before it grows a fused kernel.
 
         ``return_bids=False`` lets a caller that only folds partials
         (shard workers streaming aggregates, tighten-only ingest) skip
@@ -114,6 +116,11 @@ class Backend:
         t = IncrementalTightener(tree)
         t.update(records, bids)
         return (bids if return_bids else None), t.as_partial()
+
+    def fold_outputs(self, tree: FrozenQdTree, outputs):
+        """Phase two: the outputs of :meth:`fused_outputs` as a
+        ``TightenPartial`` (the base's outputs already are one)."""
+        return outputs
 
 
 # ---------------------------------------------------------------------------
@@ -131,9 +138,9 @@ class NumpyBackend(Backend):
         )
         return qry.queries_intersect(conj, wt)
 
-    def fused_ingest(self, tree, cache, records, return_bids=True, **opts):
+    def fused_outputs(self, tree, cache, records, return_bids=True, **opts):
         # the numpy oracle IS the bit-identity reference for every fused
-        # backend (kernels/ref.py)
+        # backend (kernels/ref.py); its outputs already are the partial
         from repro.kernels.ref import fused_ingest_ref
 
         bids, partial = fused_ingest_ref(tree, records)
@@ -474,34 +481,30 @@ class JaxBackend(Backend):
 
         return cache.get(key, build)
 
-    def fused_ingest(self, tree, cache, records, return_bids=True, **opts):
-        from repro.kernels.ref import partial_from_fused
-
-        plan = self._ingest_plan(tree, cache)
+    def fused_outputs(self, tree, cache, records, return_bids=True, **opts):
         m = records.shape[0]
         L = tree.n_leaves
-        m_bucket = pad_bucket(m, self.min_batch_bucket)
-        padded = np.zeros((m_bucket, records.shape[1]), np.int32)
-        padded[:m] = records
-        valid = np.zeros(m_bucket, bool)
-        valid[:m] = True
-        bids, counts, lo, hi, cat, advt, advf = plan.fn(
-            jnp.asarray(padded), jnp.asarray(valid)
-        )
-        partial = partial_from_fused(
-            tree,
-            np.asarray(counts)[:L],
-            np.asarray(lo)[:L],
-            np.asarray(hi)[:L],
-            np.asarray(cat)[:L],
-            np.asarray(advt)[:L],
-            np.asarray(advf)[:L],
-        )
-        # partials-only callers skip the per-row D2H (the plan still
-        # computes bids on device; only the host conversion is elided)
-        if not return_bids:
-            return None, partial
-        return np.asarray(bids[:m]).astype(np.int32), partial
+        with spans.span(spans.ENGINE_STAGE):
+            plan = self._ingest_plan(tree, cache)
+            m_bucket = pad_bucket(m, self.min_batch_bucket)
+            padded = np.zeros((m_bucket, records.shape[1]), np.int32)
+            padded[:m] = records
+            valid = np.zeros(m_bucket, bool)
+            valid[:m] = True
+            bids, *aggs = plan.fn(jnp.asarray(padded), jnp.asarray(valid))
+        with spans.span(spans.ENGINE_DEVICE_WAIT):
+            aggs = tuple(np.asarray(a)[:L] for a in aggs)
+            # partials-only callers skip the per-row D2H (the plan still
+            # computes bids on device; only the host conversion is elided)
+            bids = (
+                np.asarray(bids[:m]).astype(np.int32) if return_bids else None
+            )
+        return bids, aggs
+
+    def fold_outputs(self, tree, outputs):
+        from repro.kernels.ref import partial_from_fused
+
+        return partial_from_fused(tree, *outputs)
 
     def query_hits(self, tree, cache, wt, **opts):
         sig = planlib.tree_signature(tree)
@@ -717,49 +720,46 @@ class PallasBackend(Backend):
 
         return cache.get(key, build)
 
-    def fused_ingest(
+    def fused_outputs(
         self, tree, cache, records, tile_m: int | None = None,
         tile_l: int | None = None, interpret: bool | None = None,
         return_bids: bool = True, **opts,
     ):
-        from repro.kernels.ref import partial_from_fused
-
-        if interpret is None:
-            interpret = interpret_default()
-        if tile_m is None or tile_l is None:
-            from repro.engine import autotune
-
-            cfg = autotune.lookup("pallas", autotune.geometry_key(tree))
-            if cfg is not None and cfg.interpret != interpret:
-                cfg = None  # tiles timed in the other mode say nothing here
-            tile_m = tile_m or (cfg.tile_m if cfg else 256)
-            tile_l = tile_l or (cfg.tile_l if cfg else LANE)
-        plan = self._ingest_plan(tree, cache, tile_m, tile_l, interpret)
         m = records.shape[0]
         L = tree.n_leaves
-        m_bucket = pad_bucket(m, max(self.min_batch_bucket, tile_m))
-        if m_bucket % tile_m:  # non-power-of-two tile_m
-            m_bucket = ((m_bucket + tile_m - 1) // tile_m) * tile_m
-        padded = np.zeros((m_bucket, records.shape[1]), np.float32)
-        padded[:m] = records
-        valid = np.zeros((m_bucket, 1), np.float32)
-        valid[:m] = 1.0
-        bids, counts, lo, hi, cat, advt, advf = plan.fn(
-            jnp.asarray(padded), jnp.asarray(valid)
-        )
-        partial = partial_from_fused(
-            tree,
-            np.asarray(counts)[0, :L],
-            np.asarray(lo)[:L],
-            np.asarray(hi)[:L],
-            np.asarray(cat)[:L],
-            np.asarray(advt)[:L],
-            np.asarray(advf)[:L],
-        )
-        if not return_bids:
-            return None, partial
-        bids_np = (np.asarray(bids)[:m, 0] - 1.0).astype(np.int32)
-        return bids_np, partial
+        with spans.span(spans.ENGINE_STAGE):
+            if interpret is None:
+                interpret = interpret_default()
+            if tile_m is None or tile_l is None:
+                from repro.engine import autotune
+
+                cfg = autotune.lookup("pallas", autotune.geometry_key(tree))
+                if cfg is not None and cfg.interpret != interpret:
+                    cfg = None  # tiles timed in the other mode say nothing
+                tile_m = tile_m or (cfg.tile_m if cfg else 256)
+                tile_l = tile_l or (cfg.tile_l if cfg else LANE)
+            plan = self._ingest_plan(tree, cache, tile_m, tile_l, interpret)
+            m_bucket = pad_bucket(m, max(self.min_batch_bucket, tile_m))
+            if m_bucket % tile_m:  # non-power-of-two tile_m
+                m_bucket = ((m_bucket + tile_m - 1) // tile_m) * tile_m
+            padded = np.zeros((m_bucket, records.shape[1]), np.float32)
+            padded[:m] = records
+            valid = np.zeros((m_bucket, 1), np.float32)
+            valid[:m] = 1.0
+            bids, counts, *aggs = plan.fn(
+                jnp.asarray(padded), jnp.asarray(valid)
+            )
+        with spans.span(spans.ENGINE_DEVICE_WAIT):
+            aggs = (np.asarray(counts)[0, :L],) + tuple(
+                np.asarray(a)[:L] for a in aggs
+            )
+            bids = (
+                (np.asarray(bids)[:m, 0] - 1.0).astype(np.int32)
+                if return_bids else None
+            )
+        return bids, aggs
+
+    fold_outputs = JaxBackend.fold_outputs
 
     def query_hits(self, tree, cache, wt, interpret: bool | None = None,
                    **opts):

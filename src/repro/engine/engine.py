@@ -22,6 +22,7 @@ from __future__ import annotations
 # qdlint: deterministic-module
 
 import collections
+import contextlib
 import dataclasses
 import threading
 import time
@@ -29,6 +30,7 @@ from typing import Iterable, Iterator, Optional
 
 import numpy as np
 
+from repro import spans
 from repro.core import query as qry
 from repro.core.qdtree import FrozenQdTree, IncrementalTightener
 from repro.engine import backends as be
@@ -294,7 +296,11 @@ class LayoutEngine:
 
     # -- streaming ingestion -------------------------------------------------
     def fused_step(  # qdlint: hot-path
-        self, records: np.ndarray, backend: Optional[str] = None, **opts
+        self,
+        records: np.ndarray,
+        backend: Optional[str] = None,
+        fold: Optional[contextlib.ExitStack] = None,
+        **opts,
     ):
         """One single-pass route + tighten step (no tree mutation).
 
@@ -303,6 +309,13 @@ class LayoutEngine:
         same records, but each record is touched once (the fused kernels;
         see ``kernels/fused_ingest.py``).  The caller folds the partial
         into a tightener (``merge``) or a shard reduction.
+
+        The backend stages the batch and pulls the step's outputs (the
+        ``engine.stage`` and ``engine.device_wait`` spans); turning them
+        into the partial opens ``engine.fold``.  Given ``fold``, that span
+        is entered on the caller's stack, so the caller's own share of
+        the fold lands in the same span when it closes the stack; without
+        it, the span closes on return.
         """
         if records.shape[0] == 0:
             return (
@@ -310,9 +323,14 @@ class LayoutEngine:
                 IncrementalTightener(self.tree).as_partial(),
             )
         kw = {**self._opts(), **opts}
-        return self._backend(backend).fused_ingest(
-            self.tree, self.plans, records, **kw
-        )
+        step = self._backend(backend)
+        bids, outputs = step.fused_outputs(self.tree, self.plans, records,
+                                           **kw)
+        with contextlib.ExitStack() as own:
+            (own if fold is None else fold).enter_context(
+                spans.span(spans.ENGINE_FOLD)
+            )
+            return bids, step.fold_outputs(self.tree, outputs)
 
     def warm_ingest(
         self,
@@ -384,6 +402,10 @@ class LayoutEngine:
         tightener via ``merge`` — which is bit-identical to the legacy
         two-pass loop but touches each record once.  ``fused=False``
         restores the two-pass path (route, then host-side tighten).
+
+        The call and each phase of a batch are profiler spans
+        (``repro.spans``: ``engine.ingest``, ``engine.stage``,
+        ``engine.device_wait``, ``engine.fold``, ``engine.publish``).
         """
         traces0 = planlib.trace_counts()
         probe = (
@@ -403,33 +425,40 @@ class LayoutEngine:
         need_bids = buffers is not None or probe is not None
         n_batches = n_records = 0
         t0 = time.perf_counter()
-        for batch in batches:
-            if batch.shape[0] == 0:
-                continue
-            if use_fused:
-                bids, part = self.fused_step(
-                    batch, backend=backend, return_bids=need_bids
-                )
-            else:
-                bids = self.route(batch, backend=backend)
-            if buffers is not None:
-                buffers.append(batch, bids)
-            if use_fused:
-                tightener.merge(part)
-            elif tightener is not None:
-                tightener.update(batch, bids)
-            else:
-                sizes += np.bincount(bids, minlength=sizes.shape[0])
-            if probe is not None:
-                stat = probe.observe(bids)
-                observed = observed.merge(stat)
-                if on_observation is not None:
-                    on_observation(stat)
-            n_batches += 1
-            n_records += batch.shape[0]
-        if tightener is not None:
-            tightener.apply()
-            sizes = tightener.counts.copy()
+        with spans.span(spans.ENGINE_INGEST):
+            for batch in batches:
+                if batch.shape[0] == 0:
+                    continue
+                # one engine.fold span per batch: from the step's outputs
+                # to the partial (inside fused_step) through the merge
+                with contextlib.ExitStack() as fold:
+                    if use_fused:
+                        bids, part = self.fused_step(
+                            batch, backend=backend, fold=fold,
+                            return_bids=need_bids,
+                        )
+                    else:
+                        bids = self.route(batch, backend=backend)
+                        fold.enter_context(spans.span(spans.ENGINE_FOLD))
+                    if buffers is not None:
+                        buffers.append(batch, bids)
+                    if use_fused:
+                        tightener.merge(part)
+                    elif tightener is not None:
+                        tightener.update(batch, bids)
+                    else:
+                        sizes += np.bincount(bids, minlength=sizes.shape[0])
+                    if probe is not None:
+                        stat = probe.observe(bids)
+                        observed = observed.merge(stat)
+                        if on_observation is not None:
+                            on_observation(stat)
+                n_batches += 1
+                n_records += batch.shape[0]
+            if tightener is not None:
+                with spans.span(spans.ENGINE_PUBLISH):
+                    tightener.apply()
+                    sizes = tightener.counts.copy()
         wall = time.perf_counter() - t0
         delta = planlib.trace_delta(traces0, planlib.trace_counts())
         return IngestReport(

@@ -14,7 +14,7 @@ every served query recorded into a WorkloadTracker.
     PYTHONPATH=src python -m repro.launch.serve --workload auto
 
 Prints per-phase progress plus a final JSON summary (achieved qps, cache
-hit rate, p50/p99 latency, admission + staleness counters) like
+hit rate, mean queue wait, admission + staleness counters) like
 ``repro.launch.ingest``.
 """
 
@@ -197,8 +197,8 @@ def main(argv=None) -> dict:
         f"dispatches for {stats['counters']['dispatches']} batches"
     )
     print(
-        f"[serve] latency: p50 {stats['latency']['p50_ms']:.2f}ms "
-        f"p99 {stats['latency']['p99_ms']:.2f}ms"
+        f"[serve] queue wait: mean {stats['queue_wait_ms']:.3f}ms from "
+        f"submission to dispatch"
     )
     print(
         f"[serve] staleness audit: {stats['counters']['stale_responses']} "
@@ -216,8 +216,7 @@ def main(argv=None) -> dict:
         "served": len(results),
         "rejected": rejected,
         "hit_rate": stats["cache"]["hit_rate"],
-        "p50_ms": stats["latency"]["p50_ms"],
-        "p99_ms": stats["latency"]["p99_ms"],
+        "queue_wait_ms": stats["queue_wait_ms"],
         "stale_responses": stats["counters"]["stale_responses"],
         "counters": stats["counters"],
         "admission": stats["admission"],

@@ -10,8 +10,8 @@ Layers (each its own module, composed by :class:`QueryServer`):
   serving epoch (generation hot swaps AND in-place tighten bumps);
 * ``server`` — the dispatch core tying them to a
   :class:`~repro.service.service.LayoutService`, with the staleness
-  audit and workload-tracker observation;
-* ``stats`` — latency percentiles for the benchmark surface.
+  audit and workload-tracker observation, its counters and its
+  profiler spans (``repro.spans``).
 """
 
 from repro.serve.cache import (
@@ -30,7 +30,6 @@ from repro.serve.coalescer import (
     ServeResult,
 )
 from repro.serve.server import QueryServer, ServerCounters
-from repro.serve.stats import LatencyRecorder
 
 __all__ = [
     "EXACT_RESOLUTION",
@@ -38,7 +37,6 @@ __all__ = [
     "AdmissionStats",
     "CacheStats",
     "Epoch",
-    "LatencyRecorder",
     "QueryServer",
     "QueryTicket",
     "RequestQueue",

@@ -13,6 +13,12 @@ Request flow (one dispatch):
         tracker.record(hits + misses) + tick
         complete tickets (latency, provenance epoch, staleness audit)
 
+Each stage is a profiler span (``repro.spans``): ``serve.coalesce``
+around the dispatcher's wait for a batch, ``serve.dispatch`` around one
+dispatch, and inside it ``serve.probe`` (capture, signatures, cache
+lookup), ``serve.route`` (the misses' engine dispatch) and
+``serve.finish`` (cache inserts, tracker, completion).
+
 Soundness protocol (the worst-case framing of arXiv 2405.04984 — never
 serve block IDs from a retired layout):
 
@@ -52,6 +58,7 @@ from typing import Iterable, Optional
 
 import numpy as np
 
+from repro import spans
 from repro.core import query as qry
 from repro.engine import plan as planlib
 from repro.serve.cache import Epoch, ResultCache, exact_signatures
@@ -61,12 +68,18 @@ from repro.serve.coalescer import (
     ServeConfig,
     ServeResult,
 )
-from repro.serve.stats import LatencyRecorder
 
 
 @dataclasses.dataclass
 class ServerCounters:
-    """Monotonic dispatch-loop counters (all pinnable in CI — no timings)."""
+    """Monotonic dispatch-loop counters.
+
+    The count fields are pinnable in CI.  ``queue_wait_us`` is a timing
+    on the server's ``clock`` and is not pinned: the sum over dispatched
+    tickets of the dispatch's start minus the ticket's ``submitted_at``,
+    in whole microseconds, counted once per ticket (not again on a swap
+    retry).
+    """
 
     dispatches: int = 0  # coalesced batches processed
     engine_dispatches: int = 0  # route_queries calls (miss batches)
@@ -76,6 +89,7 @@ class ServerCounters:
     swap_retries: int = 0  # re-dispatches after a mid-route epoch move
     uncached_dispatches: int = 0  # delivered-but-not-cached miss batches
     stale_responses: int = 0  # the invariant counter: must stay 0
+    queue_wait_us: int = 0  # timing, not pinned (see the class docstring)
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -112,7 +126,6 @@ class QueryServer:
         self.clock = clock
         self.queue = RequestQueue(self.config, clock=clock)
         self.cache = ResultCache(self.config.cache_capacity)
-        self.latency = LatencyRecorder()
         self.counters = ServerCounters()
         self._mutate = threading.Lock()  # counters only
         self._running = False
@@ -173,7 +186,8 @@ class QueryServer:
 
     def _loop(self) -> None:
         while self._running:
-            batch = self.queue.next_batch(timeout=0.05)
+            with spans.span(spans.SERVE_COALESCE):
+                batch = self.queue.next_batch(timeout=0.05)
             if batch:
                 self._dispatch(batch)
 
@@ -268,89 +282,100 @@ class QueryServer:
     def _dispatch(self, tickets: list[QueryTicket]) -> None:
         if not tickets:
             return
+        start = self.clock()
+        wait_us = round(sum(start - t.submitted_at for t in tickets) * 1e6)
         cfg = self.config
-        try:
-            for attempt in range(cfg.max_swap_retries + 1):
-                rset = self.service.live_replica_set()
-                live = rset.primary
-                epochs = rset.epochs()
-                self.cache.activate(epochs)
-                wl_all = qry.Workload(
-                    live.tree.schema, tuple(t.query for t in tickets)
-                )
-                if rset.k == 1:
-                    sigs = exact_signatures(wl_all, live.tree.cuts)
-                else:
-                    sigs = exact_signatures(
-                        wl_all, adv_filter=rset.adv_filter()
-                    )
-                found = self.cache.lookup(epochs, sigs)
-                miss_index: dict[tuple, int] = {}
-                miss_queries: list[qry.Query] = []
-                for t, sig, h in zip(tickets, sigs, found):
-                    if h is None and sig not in miss_index:
-                        miss_index[sig] = len(miss_queries)
-                        miss_queries.append(t.query)
-                routed: list[np.ndarray] = []
-                miss_epochs: list[Epoch] = []
-                if miss_queries:
-                    miss_wl = qry.Workload(
-                        live.tree.schema, tuple(miss_queries)
-                    )
-                    if rset.k == 1:
-                        # tensorize against the captured tree's cuts
-                        # directly: one dispatch per miss batch, no
-                        # wt-LRU churn from ephemeral per-batch
-                        # workload objects
-                        routed = live.engine.route_queries(
-                            miss_wl.tensorize(live.tree.cuts)
+        with spans.span(spans.SERVE_DISPATCH, n=len(tickets)):
+            try:
+                for attempt in range(cfg.max_swap_retries + 1):
+                    with spans.span(spans.SERVE_PROBE):
+                        rset = self.service.live_replica_set()
+                        live = rset.primary
+                        epochs = rset.epochs()
+                        self.cache.activate(epochs)
+                        wl_all = qry.Workload(
+                            live.tree.schema, tuple(t.query for t in tickets)
                         )
-                        miss_epochs = [epochs[0]] * len(routed)
-                        n_dispatches = 1
-                    else:
-                        routes = rset.route_queries(miss_wl)
-                        routed = [r.bids for r in routes]
-                        miss_epochs = [epochs[r.replica_id]
-                                       for r in routes]
-                        n_dispatches = rset.k
-                    with self._mutate:
-                        self.counters.engine_dispatches += n_dispatches
-                        self.counters.queries_routed += len(miss_queries)
-                    # a desc_version bump mid-route means some tree's
-                    # leaf descriptions were tightened UNDER the
-                    # dispatch — results may be torn: re-dispatch
-                    torn = any(
-                        planlib.desc_version(v.tree) != e.desc_version
-                        for v, e in zip(rset.versions, epochs)
-                    )
-                    if torn:
-                        if attempt < cfg.max_swap_retries:
+                        if rset.k == 1:
+                            sigs = exact_signatures(wl_all, live.tree.cuts)
+                        else:
+                            sigs = exact_signatures(
+                                wl_all, adv_filter=rset.adv_filter()
+                            )
+                        found = self.cache.lookup(epochs, sigs)
+                        miss_index: dict[tuple, int] = {}
+                        miss_queries: list[qry.Query] = []
+                        for t, sig, h in zip(tickets, sigs, found):
+                            if h is None and sig not in miss_index:
+                                miss_index[sig] = len(miss_queries)
+                                miss_queries.append(t.query)
+                    routed: list[np.ndarray] = []
+                    miss_epochs: list[Epoch] = []
+                    if miss_queries:
+                        with spans.span(spans.SERVE_ROUTE,
+                                        misses=len(miss_queries)):
+                            routed, miss_epochs, torn = self._route(
+                                rset, live, epochs, miss_queries
+                            )
+                        if torn and attempt < cfg.max_swap_retries:
                             with self._mutate:
                                 self.counters.swap_retries += 1
                             continue
-                swapped = self.service.live_replica_set() is not rset
-                torn_now = any(
-                    planlib.desc_version(v.tree) != e.desc_version
-                    for v, e in zip(rset.versions, epochs)
-                )
-                if miss_queries and (swapped or torn_now):
-                    # deliverable (old trees are immutable across a swap)
-                    # but the epoch is retired — never cache retired
-                    # results
-                    with self._mutate:
-                        self.counters.uncached_dispatches += 1
-                else:
-                    for sig, i in miss_index.items():
-                        self.cache.put(miss_epochs[i], sig, routed[i])
-                self._record(wl_all, live)
-                self._complete(tickets, sigs, found, routed, miss_index,
-                               miss_epochs)
-                return
-        except BaseException as e:
-            for t in tickets:
-                if not t.done():
-                    t._fail(e)
-                    self.queue.release(t)
+                    with spans.span(spans.SERVE_FINISH):
+                        swapped = self.service.live_replica_set() is not rset
+                        torn_now = any(
+                            planlib.desc_version(v.tree) != e.desc_version
+                            for v, e in zip(rset.versions, epochs)
+                        )
+                        if miss_queries and (swapped or torn_now):
+                            # deliverable (old trees are immutable across a
+                            # swap) but the epoch is retired — never cache
+                            # retired results
+                            with self._mutate:
+                                self.counters.uncached_dispatches += 1
+                        else:
+                            for sig, i in miss_index.items():
+                                self.cache.put(miss_epochs[i], sig, routed[i])
+                        self._record(wl_all, live)
+                        self._complete(tickets, sigs, found, routed,
+                                       miss_index, miss_epochs, wait_us)
+                    return
+            except BaseException as e:
+                for t in tickets:
+                    if not t.done():
+                        t._fail(e)
+                        self.queue.release(t)
+
+    def _route(self, rset, live, epochs, miss_queries):
+        """Route one attempt's unique misses; returns their block lists,
+        the epoch each was routed under, and whether a description bump
+        landed under the dispatch (torn results)."""
+        miss_wl = qry.Workload(live.tree.schema, tuple(miss_queries))
+        if rset.k == 1:
+            # tensorize against the captured tree's cuts directly: one
+            # dispatch per miss batch, no wt-LRU churn from ephemeral
+            # per-batch workload objects
+            routed = live.engine.route_queries(
+                miss_wl.tensorize(live.tree.cuts)
+            )
+            miss_epochs = [epochs[0]] * len(routed)
+            n_dispatches = 1
+        else:
+            routes = rset.route_queries(miss_wl)
+            routed = [r.bids for r in routes]
+            miss_epochs = [epochs[r.replica_id] for r in routes]
+            n_dispatches = rset.k
+        with self._mutate:
+            self.counters.engine_dispatches += n_dispatches
+            self.counters.queries_routed += len(miss_queries)
+        # a desc_version bump mid-route means some tree's leaf
+        # descriptions were tightened UNDER the dispatch — results may be
+        # torn: the caller re-dispatches
+        torn = any(
+            planlib.desc_version(v.tree) != e.desc_version
+            for v, e in zip(rset.versions, epochs)
+        )
+        return routed, miss_epochs, torn
 
     def _record(self, wl_all: qry.Workload, live) -> None:
         """Tracker observation: hits and misses alike, one round per
@@ -365,12 +390,11 @@ class QueryServer:
             self.tracker.tick()
 
     def _complete(self, tickets, sigs, found, routed, miss_index,
-                  miss_epochs):
+                  miss_epochs, wait_us):
         done_at = self.clock()
         live_gens = self.service.replica_generations()
         n_cached = 0
         n_stale = 0
-        latencies = []
         for t, sig, h in zip(tickets, sigs, found):
             cached = h is not None
             if cached:
@@ -380,7 +404,6 @@ class QueryServer:
                 epoch, bids = miss_epochs[i], routed[i]
             lat = done_at - t.submitted_at
             n_cached += cached
-            latencies.append(lat)
             # the audit, per replica: a response is stale iff the serving
             # replica's generation was retired BEFORE the request was
             # submitted (rollback re-liveness is not staleness — the
@@ -410,18 +433,25 @@ class QueryServer:
             self.counters.queries_served += len(tickets)
             self.counters.queries_cached += n_cached
             self.counters.stale_responses += n_stale
-        self.latency.extend(latencies)
+            self.counters.queue_wait_us += wait_us
         self.queue.release_many(tickets)
 
     # -- stats surface -------------------------------------------------------
     def stats(self) -> dict:
+        """Queue depth, epoch, admission, cache and counters, and
+        ``queue_wait_ms``: the mean wait of a served query between its
+        submission and the start of its dispatch."""
+        counters = self.counters.as_dict()
+        served = counters["queries_served"]
         return {
             "queue_depth": len(self.queue),
             "epoch": list(self.cache.epoch) if self.cache.epoch else None,
             "admission": self.queue.stats.as_dict(),
             "cache": self.cache.snapshot(),
-            "latency": self.latency.summary(),
-            "counters": self.counters.as_dict(),
+            "queue_wait_ms": (
+                counters["queue_wait_us"] / served / 1e3 if served else 0.0
+            ),
+            "counters": counters,
         }
 
 

@@ -374,7 +374,8 @@ def test_serve_stats_surface():
     assert stats["queue_depth"] == 0
     assert stats["epoch"] == list(svc.live_epoch())
     assert stats["cache"]["lookups"] == 3
-    assert stats["latency"]["count"] == 3
+    assert stats["queue_wait_ms"] == pytest.approx(
+        stats["counters"]["queue_wait_us"] / 3 / 1e3)
     assert stats["counters"]["queries_served"] == 3
     assert stats["admission"]["accepted"] == 3
     res = server.serve(work.queries[0])
