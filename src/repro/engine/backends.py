@@ -148,16 +148,22 @@ class NumpyBackend(Backend):
 
 
 # ---------------------------------------------------------------------------
-# jitted jnp level-synchronous descent
+# jitted jnp route and fused ingest
 # ---------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=("depth",))
-def _route_jax_padded(records, ta, ca, depth):
-    count_trace("route:jax")
-    from repro.core.routing import eval_cuts_jax
+# The fused jax ingest step locates rows by the path-matrix product while
+# its plan's leaf_bucket × cut_bucket is at most this, else by the descent.
+# Whole step on one TPU v5e, 65,536 TPC-H rows, greedy trees of the
+# tpch_sf1 benchmark workload (PERF.md §6): product against descent
+# 25.9 / 93.0 ms at 1024 × 100, 50.0 / 116.8 ms at 4096 × 196 and
+# 53.3 / 118.2 ms at 16,384 × 196.  The product won at every geometry
+# measured, so the descent is kept only above the largest.
+PATHS_MAX_CELLS = 16_384 * 196
 
-    M = eval_cuts_jax(records, ca)
-    m = records.shape[0]
-    node = jnp.zeros(m, jnp.int32)
+
+def _locate_descent(M, ta, depth):
+    """Block id of each row by the level-synchronous descent: ``depth``
+    rounds of per-row gathers through the flat tree arrays ``ta``."""
+    node = jnp.zeros(M.shape[0], jnp.int32)
 
     def body(_, node):
         cid = ta["cut_id"][node]
@@ -171,32 +177,56 @@ def _route_jax_padded(records, ta, ca, depth):
     return ta["leaf_bid"][node]
 
 
+def _locate_paths(M, pa):
+    """Block id of each row from the leaves' path constraints.
+
+    A row lies in leaf ``l`` exactly when it violates none of the cuts on
+    ``l``'s path: ``viol = (1 - M) @ pathpos + M @ pathneg``, which is
+    ``path_len + M @ path_diff`` with ``path_diff = pathneg - pathpos``
+    and ``path_len`` the column sums of ``pathpos``.  Every operand is
+    0 or ±1 and a path has at most ``depth`` terms, so the product is
+    exact at any matmul precision; the one column with no violation is
+    the block id (padded columns always carry one).  On the TPU, XLA fuses
+    the product with the compare and the argmax, so the (rows, leaves)
+    counts are never written out.
+    """
+    viol = pa["path_len"] + jnp.dot(M.astype(jnp.float32), pa["path_diff"])
+    return jnp.argmax(viol < 0.5, axis=1).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("depth",))
+def _route_jax_padded(records, ta, ca, depth):
+    count_trace("route:jax")
+    from repro.core.routing import eval_cuts_jax
+
+    return _locate_descent(eval_cuts_jax(records, ca), ta, depth)
+
+
 @functools.partial(
     jax.jit,
-    static_argnames=("depth", "l_dump", "cat_cols", "cat_gemm", "bits",
-                     "n_adv"),
+    static_argnames=("locate", "depth", "l_dump", "cat_cols", "cat_gemm",
+                     "bits", "n_adv"),
 )
-def _ingest_jax_padded(records, valid, ta, ca, depth, l_dump, cat_cols,
-                       cat_gemm, bits, n_adv):
-    """Fused single-pass ingest: routing descent + segment reductions.
-
-    One jit replaces the two-pass hot path (jitted route, then the numpy
-    tightener's ``np.minimum.at``/``bincount`` scatters): the descent and
-    all per-leaf reductions trace into a single compiled program, so each
-    record is touched once.  Two structural optimizations carry the
-    speedup over the two-pass baseline on CPU:
+def _ingest_jax_padded(records, valid, loc, ca, locate, depth, l_dump,
+                       cat_cols, cat_gemm, bits, n_adv):
+    """Fused single-pass ingest: predicate matrix, leaf location and the
+    per-leaf reductions in one compiled program, so each record is
+    touched once.
 
     * ``ca`` holds only the cuts the tree's internal nodes reference
-      (pruned + remapped by ``_ingest_plan``) — the route plan evaluates
-      the full candidate table, most of which no descent ever reads;
-    * the pruned table arrives *grouped by kind* (``[range | IN | adv]``
-      segments, each padded to its own bucket), so range cuts are pure
-      vector compares and the expensive per-cut bit gathers run only
-      over the IN segment instead of the whole table;
+      (pruned + remapped by ``_ingest_plan``), *grouped by kind*
+      (``[range | IN | adv]`` segments, each padded to its own bucket),
+      so range cuts are pure vector compares and the per-cut bit gathers
+      run only over the IN segment;
+    * each row's block comes from one path-matrix product against the
+      predicate matrix (``locate="paths"``, :func:`_locate_paths`), or
+      from ``depth`` rounds of per-row gathers (``"descent"``) on a plan
+      too large for the product (``PATHS_MAX_CELLS``); ``loc`` holds
+      that mechanism's operands;
     * counts / categorical bits / adv flags all come out of ONE one-hot
       matmul (``leaf-onehotᵀ @ [1 | value-onehots | t | ~t]``) instead of
-      per-element scatters, which XLA:CPU executes serially.  The f32
-      accumulations are exact: 0/1 summands, totals < 2**24.
+      per-element scatters.  The f32 accumulations are exact: 0/1
+      summands, totals < 2**24.
 
     Padding rows are redirected to a dump row (``l_dump - 1``) that the
     caller slices off; dictionary codes are int32 throughout, so the
@@ -246,18 +276,10 @@ def _ingest_jax_padded(records, valid, ta, ca, depth, l_dump, cat_cols,
             ],
             axis=1,
         )
-    node = jnp.zeros(m, jnp.int32)
-
-    def body(_, node):
-        cid = ta["cut_id"][node]
-        pred = jnp.take_along_axis(
-            M, jnp.clip(cid, 0)[:, None].astype(jnp.int32), axis=1
-        )[:, 0]
-        nxt = jnp.where(pred, ta["left"][node], ta["right"][node])
-        return jnp.where(cid >= 0, nxt, node)
-
-    node = jax.lax.fori_loop(0, depth, body, node)
-    bids = ta["leaf_bid"][node]
+    if locate == "paths":
+        bids = _locate_paths(M, loc)
+    else:
+        bids = _locate_descent(M, loc, depth)
 
     d = records.shape[1]
     i32 = jnp.iinfo(jnp.int32)
@@ -413,24 +435,42 @@ class JaxBackend(Backend):
         cut_bucket = nr_pad + ni_pad + na_pad
         # dump row past the bucketed leaf axis absorbs padding rows
         l_dump = leaf_bucket + 1
+        locate = (
+            "paths" if leaf_bucket * cut_bucket <= PATHS_MAX_CELLS
+            else "descent"
+        )
         key = PlanKey(
             sig, "jax", 0, node_bucket, leaf_bucket, cut_bucket,
-            ("ingest", depth_bucket, nr_pad, ni_pad, na_pad),
+            ("ingest", locate, depth_bucket, nr_pad, ni_pad, na_pad),
         )
 
         def build():
             schema = tree.schema
-            ta_np = planlib.pack_tree_arrays(tree, node_bucket)
-            # remap node cut ids into the grouped table: segment base +
-            # position within segment
+            # cut ids into the grouped table: segment base + position
+            # within segment
             remap = np.full(max(tree.cuts.n_cuts, 1), -1, np.int64)
             remap[seg_r] = np.arange(seg_r.size)
             remap[seg_i] = nr_pad + np.arange(seg_i.size)
             remap[seg_a] = nr_pad + ni_pad + np.arange(seg_a.size)
-            cid = ta_np["cut_id"]
-            ta_np["cut_id"] = np.where(
-                cid >= 0, remap[np.maximum(cid, 0)], -1
-            ).astype(cid.dtype)
+            if locate == "paths":
+                pos, neg = planlib.path_matrices(tree)
+                gpos = np.zeros((cut_bucket, leaf_bucket), np.float32)
+                gneg = np.zeros((cut_bucket, leaf_bucket), np.float32)
+                gpos[remap[used], : tree.n_leaves] = pos[used]
+                gneg[remap[used], : tree.n_leaves] = neg[used]
+                # a padded leaf column requires its first cut both true
+                # and false, so it never matches
+                gpos[0, tree.n_leaves:] = gneg[0, tree.n_leaves:] = 1.0
+                loc_np = {
+                    "path_diff": gneg - gpos,
+                    "path_len": gpos.sum(axis=0),
+                }
+            else:
+                loc_np = planlib.pack_tree_arrays(tree, node_bucket)
+                cid = loc_np["cut_id"]
+                loc_np["cut_id"] = np.where(
+                    cid >= 0, remap[np.maximum(cid, 0)], -1
+                ).astype(cid.dtype)
             ca_full = planlib.pack_cut_arrays(
                 tree, pad_bucket(tree.cuts.n_cuts, 16)
             )
@@ -453,7 +493,7 @@ class JaxBackend(Backend):
             }
             if ca_np["mask_i"].shape[1] == 0:  # no cat bits anywhere
                 ca_np["mask_i"] = np.zeros((ni_pad, 1), bool)
-            ta = {k: jnp.asarray(v) for k, v in ta_np.items()}
+            loc = {k: jnp.asarray(v) for k, v in loc_np.items()}
             ca = {k: jnp.asarray(v) for k, v in ca_np.items()}
             off = np.maximum(schema.cat_offsets, 0)
             bits = max(int(schema.total_cat_bits), 1)
@@ -470,13 +510,14 @@ class JaxBackend(Backend):
                 not cat_cols or running == int(schema.total_cat_bits)
             )
             fn = functools.partial(
-                _ingest_jax_padded, ta=ta, ca=ca, depth=depth_bucket,
-                l_dump=l_dump, cat_cols=tuple(cat_cols),
+                _ingest_jax_padded, loc=loc, ca=ca, locate=locate,
+                depth=depth_bucket, l_dump=l_dump, cat_cols=tuple(cat_cols),
                 cat_gemm=cat_gemm, bits=bits, n_adv=tree.cuts.n_adv,
             )
             return CompiledPlan(
-                key=key, fn=fn, operands={"ta": ta, "ca": ca},
-                meta={"depth": depth_bucket, "l_dump": l_dump},
+                key=key, fn=fn, operands={"loc": loc, "ca": ca},
+                meta={"locate": locate, "depth": depth_bucket,
+                      "l_dump": l_dump},
             )
 
         return cache.get(key, build)

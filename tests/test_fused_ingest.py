@@ -16,9 +16,12 @@ try:
 except ImportError:  # tier-1 containers without hypothesis
     from tests._hypothesis_shim import given, settings, st
 
+from repro.core import predicates as preds
+from repro.core.predicates import Column, CutTableBuilder, Schema
 from repro.core.qdtree import IncrementalTightener
 from repro.engine import LayoutEngine, replicate_tree, sharded_ingest
-from repro.engine import autotune
+from repro.engine import autotune, backends
+from repro.engine import plan as planlib
 from repro.engine.sharded import micro_batches
 from repro.kernels.ref import fused_ingest_ref
 from tests.test_qdtree import random_tree, small_setup
@@ -101,6 +104,132 @@ def test_fused_ingest_bit_identical_to_two_pass(data):
     assert not rep2.fused and repf.fused
     np.testing.assert_array_equal(repf.block_sizes, rep2.block_sizes)
     assert _trees_identical(fused, legacy)
+
+
+def _kind_tree(kinds, seed, splits, m=512):
+    """Records and a random frozen tree whose cut table holds only cuts of
+    ``kinds`` (``"range"``, ``"in"``, ``"adv"``)."""
+    schema = Schema((
+        Column("x", "numeric", 64),
+        Column("y", "numeric", 32),
+        Column("c", "categorical", 6),
+        Column("d", "categorical", 9),
+    ))
+    rng = np.random.default_rng(seed)
+    records = np.stack(
+        [rng.integers(0, c.dom, m) for c in schema.columns], axis=1
+    ).astype(np.int32)
+    b = CutTableBuilder(schema)
+    if "range" in kinds:
+        for c in (8, 16, 24, 32, 48):
+            b.add_range(0, preds.OP_LT, c)
+        for c in (8, 16, 24):
+            b.add_range(1, preds.OP_LT, c)
+    if "in" in kinds:
+        for vals in ([0, 1], [2], [3, 4, 5], [1, 3]):
+            b.add_in(2, vals)
+        for vals in ([0, 1, 2], [4], [5, 6, 7, 8], [2, 7]):
+            b.add_in(3, vals)
+    if "adv" in kinds:
+        b.add_adv(0, preds.OP_LT, 1)
+        b.add_adv(1, preds.OP_GE, 0)
+    cuts = b.build()
+    tree = random_tree(schema, cuts, records, rng, max_splits=splits)
+    return records, tree.freeze()
+
+
+def _used_kinds(tree) -> set:
+    names = {preds.KIND_RANGE: "range", preds.KIND_IN: "in",
+             preds.KIND_ADV: "adv"}
+    used = tree.cut_id[tree.cut_id >= 0]
+    return {names[int(k)] for k in tree.cuts.kind[used]}
+
+
+@pytest.mark.parametrize(
+    "kinds,splits,m,locate",
+    [
+        (("range",), 12, 512, "paths"),
+        (("in",), 12, 512, "paths"),
+        (("range", "adv"), 12, 512, "paths"),
+        ((), 0, 512, "paths"),
+        (("range", "in", "adv"), 12, 300, "paths"),
+        (("range", "in", "adv"), 12, 300, "descent"),
+    ],
+    ids=["range_only", "in_heavy", "advanced", "single_leaf",
+         "padded_rows", "past_crossover"],
+)
+def test_jax_ingest_locate_matches_oracle(monkeypatch, kinds, splits, m,
+                                          locate):
+    """The jax step's leaf location — the path-matrix product, or the
+    descent on a plan past ``PATHS_MAX_CELLS`` — reproduces the numpy
+    reference bit for bit: bids and every aggregate, padded rows (a batch
+    off its row bucket) included."""
+    if locate == "descent":
+        monkeypatch.setattr(backends, "PATHS_MAX_CELLS", 0)
+    records, tree = _kind_tree(kinds, 3, splits)
+    assert _used_kinds(tree) == set(kinds)
+    assert (tree.n_leaves == 1) == (splits == 0)
+    batch = records[:m]
+    eng = LayoutEngine(replicate_tree(tree), backend="jax")
+    bids, partial = eng.fused_step(batch)
+    plan = backends.get_backend("jax")._ingest_plan(eng.tree, eng.plans)
+    assert plan.meta["locate"] == locate
+    want_bids, want_partial = fused_ingest_ref(tree, batch)
+    np.testing.assert_array_equal(bids, want_bids)
+    assert _partials_identical(partial, want_partial)
+
+
+def _cell_geometry_tree():
+    """A random tree at the tpch_sf1 cell's ingest geometry: 513-1024
+    leaves (leaf bucket 1024) over 64 range, 28 IN and 1 advanced cut
+    (grouped cut bucket 64 + 32 + 4)."""
+    schema = Schema((
+        Column("x", "numeric", 1024),
+        Column("y", "numeric", 1024),
+        Column("c", "categorical", 16),
+        Column("d", "categorical", 16),
+    ))
+    rng = np.random.default_rng(0)
+    records = np.stack(
+        [rng.integers(0, c.dom, 6000) for c in schema.columns], axis=1
+    ).astype(np.int32)
+    b = CutTableBuilder(schema)
+    for dim in (0, 1):
+        for c in range(16, 1008, 31):
+            b.add_range(dim, preds.OP_LT, c)
+    for dim in (2, 3):
+        for v in range(14):
+            b.add_in(dim, [v, (v + 5) % 16])
+    b.add_adv(0, preds.OP_LT, 1)
+    cuts = b.build()
+    assert cuts.n_cuts == 64 + 28 + 1
+    tree = random_tree(schema, cuts, records, rng, max_splits=700)
+    return records, tree.freeze()
+
+
+def test_jax_ingest_plan_locates_by_shape(monkeypatch):
+    """At the tpch_sf1 geometry the plan takes the path-matrix product;
+    a plan past the crossover keeps the descent; same-bucket batches
+    reuse the compiled step (no trace)."""
+    records, tree = _cell_geometry_tree()
+    jax_be = backends.get_backend("jax")
+    eng = LayoutEngine(replicate_tree(tree), backend="jax")
+    plan = jax_be._ingest_plan(eng.tree, eng.plans)
+    assert (plan.key.leaf_bucket, plan.key.cut_bucket) == (1024, 100)
+    assert plan.meta["locate"] == "paths"
+
+    eng.fused_step(records[:300])
+    before = planlib.trace_counts()
+    for n in (257, 400, 512):
+        bids, _ = eng.fused_step(records[:n])
+        np.testing.assert_array_equal(bids, tree.route(records[:n]))
+    assert planlib.trace_delta(before, planlib.trace_counts()) == {}
+
+    monkeypatch.setattr(backends, "PATHS_MAX_CELLS", 1024 * 100 - 1)
+    past = LayoutEngine(replicate_tree(tree), backend="jax")
+    assert jax_be._ingest_plan(past.tree, past.plans).meta["locate"] == (
+        "descent"
+    )
 
 
 def test_fused_partial_merge_across_batches_matches_one_shot():

@@ -124,3 +124,40 @@ def test_query_intersect_compiles_at_default_tiles(f32):
         n_adv=A,
         interpret=False,
     ))
+
+
+def test_jax_ingest_step_compiles(f32):
+    """The default backend's fused ingest step at the tpch_sf1 cell's
+    geometry: 65,536 rows x 21 columns, leaf bucket 1024, grouped cut
+    bucket 64 range + 32 IN + 4 advanced.  Rows are located by the
+    path-matrix product, so the compiled step has no descent loop."""
+    from repro.core.predicates import OP_LT, CutTableBuilder
+    from repro.core.qdtree import singleton_tree
+    from repro.engine import backends
+    from repro.engine.plan import PlanCache
+
+    schema = datagen.tpch_like_schema()
+    b = CutTableBuilder(schema)
+    b.add_adv(1, OP_LT, 2)  # l_commitdate < l_receiptdate
+    b.add_adv(0, OP_LT, 1)  # l_shipdate < l_commitdate
+    tree = singleton_tree(schema, b.build(), np.arange(1)).freeze()
+    plan = backends.get_backend("jax")._ingest_plan(tree, PlanCache())
+    assert plan.meta["locate"] == "paths"
+    one_chip = f32(1).sharding
+    seg = {"dim_r": 64, "cut_r": 64, "dim_i": 32, "off_i": 32,
+           "mask_i": 32, "advsel": 4, "adv": A}
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    kw = dict(plan.fn.keywords)
+    kw.update(
+        loc={"path_diff": f32(100, 1024), "path_len": f32(1024)},
+        ca={k: shaped((seg[k],) + a.shape[1:], a.dtype)
+            for k, a in kw["ca"].items()},
+        l_dump=1025, depth=32,
+    )
+    compiled = plan.fn.func.lower(
+        shaped((M, D), jnp.int32), shaped((M,), jnp.bool_), **kw
+    ).compile()
+    assert " while(" not in compiled.as_text()
