@@ -42,8 +42,8 @@ def main(argv=None) -> int:
     for seed in (int(s) for s in args.seeds.split(",")):
         for name, program in (("program", Program()),
                               ("control", StaleDescriptions())):
-            out = cells.run(cfg, mix, seed, args.seconds, False, program,
-                            lambda m: None)
+            out = cells.run(spec, cfg, mix, seed, args.seconds, False,
+                            program, lambda m: None)
             print(json.dumps({
                 "workload": args.workload, "seed": seed, "side": name,
                 "correct": out.correct,

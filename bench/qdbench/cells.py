@@ -143,16 +143,18 @@ def fingerprint(layout: dict) -> str:
     return h.hexdigest()[:12]
 
 
-def set_up_layout(cfg: dict, seed: int, program, log: Callable) -> dict:
-    """Table, build queries and layout of a configuration.  The table
+def set_up_layout(spec, cfg: dict, seed: int, program,
+                  log: Callable) -> dict:
+    """Table, build queries and layout of a configuration, from the files
+    ``spec`` finds by the names the configuration gives.  The table
     comes from the run's seed; the layout is learned on a bootstrap sample
     of ``sample_rows`` rows and the build queries, both made from the
     configuration's ``layout_seed``, so it is the same for every seed."""
     t = time.perf_counter()
-    schema, table = datagen.make_table(cfg["table"], cfg["rows"], seed)
-    _, sample = datagen.make_table(cfg["table"], cfg["sample_rows"],
+    schema, table = datagen.make_table(spec, cfg["table"], cfg["rows"], seed)
+    _, sample = datagen.make_table(spec, cfg["table"], cfg["sample_rows"],
                                    cfg["layout_seed"])
-    build = datagen.make_queries(cfg["query_family"], schema,
+    build = datagen.make_queries(spec, cfg["query_family"], schema,
                                  cfg["build_queries"], cfg["layout_seed"])
     t_gen = time.perf_counter() - t
     t = time.perf_counter()
@@ -179,11 +181,11 @@ def _phases(log: Callable, phases: dict) -> None:
 # ---------------------------------------------------------------------------
 # bulk load
 # ---------------------------------------------------------------------------
-def run_ingest(cfg: dict, mix: dict, seed: int, seconds: float, traced: bool,
-               program, log: Callable) -> Outcome:
+def run_ingest(spec, cfg: dict, mix: dict, seed: int, seconds: float,
+               traced: bool, program, log: Callable) -> Outcome:
     counter, pauses = CompileCounter(), GcPauses()
     t0 = time.perf_counter()
-    lay = set_up_layout(cfg, seed, program, log)
+    lay = set_up_layout(spec, cfg, seed, program, log)
     svc, table, layout, schema = (lay["svc"], lay["table"], lay["layout"],
                                   lay["schema"])
     per_call = tr.rows_per_call(mix, cfg, table.shape[1])
@@ -353,11 +355,11 @@ def lateness_log(a: Answers) -> str:
             f"(worst in second {worst} of the window)")
 
 
-def run_serve(cfg: dict, mix: dict, seed: int, seconds: float, traced: bool,
-              program, log: Callable) -> Outcome:
+def run_serve(spec, cfg: dict, mix: dict, seed: int, seconds: float,
+              traced: bool, program, log: Callable) -> Outcome:
     counter, pauses = CompileCounter(), GcPauses()
     t0 = time.perf_counter()
-    lay = set_up_layout(cfg, seed, program, log)
+    lay = set_up_layout(spec, cfg, seed, program, log)
     svc, table, layout, schema = (lay["svc"], lay["table"], lay["layout"],
                                   lay["schema"])
     t = time.perf_counter()
@@ -369,8 +371,8 @@ def run_serve(cfg: dict, mix: dict, seed: int, seconds: float, traced: bool,
     rate = mix["rate_qps"]
     warm_due = tr.schedule(rate, mix["warm_seconds"], seed + 1)
     due = tr.schedule(rate, seconds, seed)
-    warm_plain = tr.requests(mix, cfg, schema, len(warm_due), seed + 1)
-    plain = tr.requests(mix, cfg, schema, len(due), seed)
+    warm_plain = tr.requests(spec, mix, cfg, schema, len(warm_due), seed + 1)
+    plain = tr.requests(spec, mix, cfg, schema, len(due), seed)
     lay["phases"]["traffic"] = time.perf_counter() - t
 
     server = program.server(svc)
@@ -452,6 +454,9 @@ def run_serve(cfg: dict, mix: dict, seed: int, seconds: float, traced: bool,
 KINDS = {"bulk_load": run_ingest, "open_loop": run_serve}
 
 
-def run(cfg: dict, mix: dict, seed: int, seconds: float, traced: bool,
+def run(spec, cfg: dict, mix: dict, seed: int, seconds: float, traced: bool,
         program, log: Callable) -> Outcome:
-    return KINDS[mix["kind"]](cfg, mix, seed, seconds, traced, program, log)
+    """One run of a configuration under a mix; ``spec`` finds the files
+    they name."""
+    return KINDS[mix["kind"]](spec, cfg, mix, seed, seconds, traced, program,
+                              log)

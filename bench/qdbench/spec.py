@@ -1,13 +1,19 @@
 """``BENCHMARK.json`` and the files it names, found by name.
 
 A configuration is ``configs/<name>.json`` (the path ``BENCHMARK.json``
-gives), a traffic mix is ``traffic/<name>.json`` and a per-layer metric
-is ``metrics/<name>.py``, all under ``bench/``.  Adding a cell, a mix or
-a metric adds files and entries; no file here changes.
+gives) and a traffic mix is ``traffic/<name>.json``.  The code a
+configuration or a mix names is a module found by :meth:`Spec.load`: its
+table is ``tables/<table>.py``, its query family
+``families/<query_family>.py``, an open-loop mix's request draw
+``requests/<requests>.py``, and a per-layer metric is
+``metrics/<name>.py``, all under the spec's ``bench/``.  Adding a cell, a
+mix, a metric, a table, a query family or a request draw adds files and
+entries; no file here changes.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import pathlib
@@ -49,10 +55,22 @@ class Spec:
 
     def reader(self, metric: str):
         """The ``read(layer, peaks)`` function of a per-layer metric."""
-        path = self.bench / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            "qdbench_metric_" + metric.replace(".", "_").replace("-", "_"),
-            path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return self.load("metrics", metric).read
+
+    def load(self, kind: str, name: str):
+        """The module ``bench/<kind>/<name>.py`` of this spec, loaded once
+        per process: ``kind`` is ``tables``, ``families``, ``requests`` or
+        ``metrics``."""
+        return _module(self.bench / kind / f"{name}.py")
+
+
+@functools.cache
+def _module(path: pathlib.Path):
+    kind, name = path.parent.name, path.stem
+    if not path.is_file():
+        raise FileNotFoundError(f"no {kind} file named {name!r}: {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"qdbench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

@@ -12,9 +12,10 @@ Two kinds of mix exist:
                 seed gets the same number of requests, the same multiset
                 of gaps and the same mix of templates, in another
                 order, so the seed changes which work arrives when and
-                never how much.  ``requests: fresh`` draws a new
-                instance of the family's templates for every request,
-                equally many of each template.
+                never how much.  The mix's ``requests`` names the draw
+                of the queries, ``requests/<requests>.py`` under
+                ``bench/``, whose ``requests(spec, traffic, cfg, schema,
+                n, seed)`` gives them in arrival order.
 """
 
 from __future__ import annotations
@@ -22,8 +23,6 @@ from __future__ import annotations
 import time
 
 import numpy as np
-
-from qdbench import datagen
 
 
 def rows_per_call(traffic: dict, cfg: dict, n_cols: int) -> int:
@@ -86,7 +85,7 @@ def schedule(rate: float, seconds: float, seed: int) -> np.ndarray:
     return due * (seconds / gaps.sum())
 
 
-def _apportion(weights: np.ndarray, n: int) -> np.ndarray:
+def apportion(weights: np.ndarray, n: int) -> np.ndarray:
     """Integer counts summing to ``n`` in proportion to ``weights``
     (largest remainders first, ties to the lower index)."""
     exact = weights / weights.sum() * n
@@ -96,14 +95,8 @@ def _apportion(weights: np.ndarray, n: int) -> np.ndarray:
     return counts
 
 
-def requests(traffic: dict, cfg: dict, schema, n: int, seed: int) -> list:
+def requests(spec, traffic: dict, cfg: dict, schema, n: int,
+             seed: int) -> list:
     """The ``n`` plain queries of an open-loop mix, in arrival order."""
-    if traffic["requests"] != "fresh":
-        raise ValueError(f"unknown request kind {traffic['requests']!r}")
-    rng = np.random.default_rng(seed)
-    family = cfg["query_family"]
-    names = datagen.template_names(family, schema)
-    picks = np.repeat(np.arange(len(names)),
-                      _apportion(np.ones(len(names)), n))
-    rng.shuffle(picks)
-    return [datagen.instance(family, schema, names[i], rng) for i in picks]
+    draw = spec.load("requests", traffic["requests"])
+    return draw.requests(spec, traffic, cfg, schema, n, seed)

@@ -96,8 +96,9 @@ def main(argv=None) -> int:
 
     from qdbench.system import Program
 
-    out = cells.run(spec.config(cell["config"]), spec.traffic(cell["traffic"]),
-                    args.seed, args.seconds, bool(args.trace), Program(), log)
+    out = cells.run(spec, spec.config(cell["config"]),
+                    spec.traffic(cell["traffic"]), args.seed, args.seconds,
+                    bool(args.trace), Program(), log)
     log(f"peak device memory {out.memory_peak_bytes} bytes")
     line = result_line(out, spec, args.workload, bool(args.trace), devs, peaks)
     for k, c in line["checks"].items():

@@ -21,7 +21,7 @@ import run as bench
 from qdbench import cells, hostspans, spec as speclib, trace as tracing, work
 
 
-def measure(cfg: dict, mix: dict, seed: int, seconds: float, program,
+def measure(spec, cfg: dict, mix: dict, seed: int, seconds: float, program,
             log) -> tuple:
     """One traced run of a cell; returns its outcome and the span report.
 
@@ -36,7 +36,7 @@ def measure(cfg: dict, mix: dict, seed: int, seconds: float, program,
 
     tracing.read = read_and_keep
     try:
-        out = cells.run(cfg, mix, seed, seconds, True, program, log)
+        out = cells.run(spec, cfg, mix, seed, seconds, True, program, log)
     finally:
         tracing.read = reduce
     return out, hostspans.report(kept["window"], kept["lines"], out.layer)
@@ -67,7 +67,7 @@ def main(argv=None) -> int:
 
     from qdbench.system import Program
 
-    out, spans = measure(spec.config(cell["config"]),
+    out, spans = measure(spec, spec.config(cell["config"]),
                          spec.traffic(cell["traffic"]), args.seed,
                          args.seconds, Program(), bench.log)
     line = bench.result_line(out, spec, args.workload, True, devs,

@@ -44,7 +44,7 @@ def main(argv=None) -> int:
 
     cfg, mix = spec.config(cell["config"]), spec.traffic(cell["traffic"])
     program = Program()
-    lay = cells.set_up_layout(cfg, args.seed, program, print)
+    lay = cells.set_up_layout(spec, cfg, args.seed, program, print)
     svc, schema = lay["svc"], lay["schema"]
     stream = tr.IngestStream(lay["table"], cfg["batch"], cfg["batch"])
     program.ingest(svc, stream.whole_table())
@@ -54,7 +54,7 @@ def main(argv=None) -> int:
         for k, rate in enumerate(float(r) for r in args.rates.split(",")):
             seed = args.seed + 1000 * (k + 1)
             due = tr.schedule(rate, args.seconds, seed)
-            plain = tr.requests(mix, cfg, schema, len(due), seed)
+            plain = tr.requests(spec, mix, cfg, schema, len(due), seed)
             if k == 0:
                 widest = max(len(q) for q in plain)
                 program.warm_server(server, schema,

@@ -10,14 +10,17 @@ for the others.
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
 import pathlib
 import re
 import shutil
 import sys
+import textwrap
 import threading
 import time
+import types
 from collections import Counter
 
 import numpy as np
@@ -37,6 +40,21 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 def _config(name: str) -> dict:
     return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+
+
+def _found(kind: str, suffix: str = "py") -> list:
+    """Names of the files of one kind under ``bench/``: tables, families,
+    configs."""
+    return sorted(p.stem for p in (BENCH / kind).glob(f"*.{suffix}"))
+
+
+def _tpch_schema():
+    return SPEC.load("tables", "tpch").schema()
+
+
+# family -> the table of a configuration that names it
+TABLE_OF = {c["query_family"]: c["table"] for c in
+            (_config(name) for name in _found("configs", "json"))}
 
 
 # ---------------------------------------------------------------------------
@@ -72,6 +90,11 @@ def test_every_cell_finds_its_files_by_name(cell):
     mix = SPEC.traffic(w["traffic"])
     assert cfg["name"] == w["config"]
     assert mix["kind"] in cells.KINDS
+    assert len(SPEC.load("tables", cfg["table"]).schema()) == cfg["columns"]
+    family = SPEC.load("families", cfg["query_family"])
+    assert cfg["build_queries"] % family.MULTIPLE == 0
+    if mix["kind"] == "open_loop":
+        assert callable(SPEC.load("requests", mix["requests"]).requests)
     for m in SPEC.per_layer(cell):
         assert callable(SPEC.reader(m["name"]))
 
@@ -110,14 +133,147 @@ def test_a_new_metric_mix_and_config_are_found_without_editing(tmp_path):
         m["name"] for m in spec.per_layer("tpch_sf1.ingest")}
 
 
+TOY_TABLE = """
+import numpy as np
+from qdbench.datagen import Col, Schema
+
+def schema():
+    return Schema((Col("x0", "numeric", 100), Col("x1", "categorical", 6),
+                   Col("x2", "numeric", 50)))
+
+def make(n, seed):
+    rng = np.random.default_rng(seed)
+    return np.stack([rng.integers(0, 100, n), rng.zipf(1.5, n) % 6,
+                     rng.integers(0, 50, n)], axis=1).astype(np.int32)
+"""
+TOY_FAMILY = """
+import numpy as np
+from qdbench.datagen import in_atom
+
+MULTIPLE = 1
+
+def templates(schema):
+    def t(rng):
+        return ((("range", 0, "<", int(rng.integers(1, 100))),
+                 in_atom(1, rng.choice(6, 2, replace=False))),)
+    return {"t": t}
+
+def queries(schema, n, seed):
+    rng = np.random.default_rng(seed)
+    return [templates(schema)["t"](rng) for _ in range(n)]
+"""
+TOY_DRAW = """
+import numpy as np
+from qdbench import datagen
+
+def requests(spec, traffic, cfg, schema, n, seed):
+    build = datagen.make_queries(spec, cfg["query_family"], schema,
+                                 cfg["build_queries"], cfg["layout_seed"])
+    return [build[i] for i in
+            np.random.default_rng(seed).integers(0, len(build), n)]
+"""
+def _digests(root: pathlib.Path) -> dict:
+    return {str(p.relative_to(root)): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in sorted(root.rglob("*"))
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+def test_a_new_table_family_and_request_draw_run_without_editing(tmp_path):
+    """A new deployment is new files and new entries: a copy of ``bench/``
+    given a table, a query family and a request draw of its own runs a
+    bulk-load and an open-loop cell of them, correct, with every file it
+    had left as it was."""
+    from qdbench.system import Program
+
+    ignore = shutil.ignore_patterns("__pycache__", "tests")
+    shutil.copytree(BENCH, tmp_path / "bench", ignore=ignore)
+    before = _digests(tmp_path / "bench")
+    assert before == {k: v for k, v in _digests(BENCH).items()
+                      if not k.startswith("tests/")}
+    new = {"tables/toy.py": TOY_TABLE, "families/toy.py": TOY_FAMILY,
+           "requests/toy_repeat.py": TOY_DRAW,
+           "configs/toy.json": json.dumps({
+               "name": "toy", "table": "toy", "rows": 12000, "columns": 3,
+               "query_family": "toy", "build_queries": 12,
+               "strategy": "greedy", "sample_rows": 600, "layout_seed": 0,
+               "min_block": 40, "max_adv": 1, "batch": 2048}),
+           "traffic/toy_load.json": json.dumps({
+               "kind": "bulk_load", "call_bytes": 2048 * 4 * 3 * 2,
+               "check_calls": 2}),
+           "traffic/toy_open.json": json.dumps({
+               "kind": "open_loop", "requests": "toy_repeat", "rate_qps": 100,
+               "warm_seconds": 0.3, "check_requests": 0,
+               "filter_requests": 4})}
+    for rel, text in new.items():
+        (tmp_path / "bench" / rel).write_text(textwrap.dedent(text))
+    data = json.loads(json.dumps(SPEC.data))
+    data["configs"].append({"name": "toy", "source": "x", "reduced": [],
+                            "file": "bench/configs/toy.json", "why": "x"})
+    data["workloads"] += [
+        {"name": "toy.load", "config": "toy", "traffic": "toy_load",
+         "chips": 1, "why": "x"},
+        {"name": "toy.serve", "config": "toy", "traffic": "toy_open",
+         "chips": 1, "why": "x"}]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(data))
+
+    spec = speclib.Spec(tmp_path)
+    for cell in ("toy.load", "toy.serve"):
+        w = spec.cell(cell)
+        out = cells.run(spec, spec.config(w["config"]),
+                        spec.traffic(w["traffic"]), 2**31 + 5, 0.4, False,
+                        Program(), lambda m: None)
+        assert out.correct and out.failed == 0 and out.attempted > 0, \
+            (cell, out.checks)
+    after = _digests(tmp_path / "bench")
+    assert {k: after[k] for k in before} == before
+    assert set(after) - set(before) == set(new)
+
+
+def test_a_missing_file_or_template_is_named():
+    with pytest.raises(FileNotFoundError, match=r"tables/absent\.py"):
+        datagen.make_table(SPEC, "absent", 10, 0)
+    with pytest.raises(FileNotFoundError, match=r"requests/absent\.py"):
+        tr.requests(SPEC, {"requests": "absent"}, {}, _tpch_schema(), 10, 0)
+    bare = types.SimpleNamespace(__file__="families/bare.py",
+                                 queries=lambda schema, n, seed: [])
+    only_bare = types.SimpleNamespace(load=lambda kind, name: bare)
+    with pytest.raises(ValueError, match="'bare' has no templates"):
+        datagen.templates(only_bare, "bare", _tpch_schema())
+
+
 # ---------------------------------------------------------------------------
 # generators
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("table,width", [("tpch", 21)])
+GOLDEN = {  # SHA-256 of the TPC-H generators' output before they became files
+    "table": "22635f4a3f8babc5f5c1febff31c557b7e4e53845794171b2296b87e81257c73",
+    "queries": "cad958b80debdf5040963b3fa1c6973706ec992ba3c65cd15254eb55f8439462",
+    "requests": "645c3c8541cda8349774c0b8b7a9f92f7073abb3b7350b95c96a6cf8438da7f6",
+}
+
+
+def test_tpch_generators_match_their_golden_digests():
+    schema, table = datagen.make_table(SPEC, "tpch", 5000, 123)
+    cfg = _config("tpch_sf1")
+    build = datagen.make_queries(SPEC, "tpch", schema, 75, cfg["layout_seed"])
+    plain = tr.requests(SPEC, SPEC.traffic("tpch_adhoc_fresh"), cfg, schema,
+                        300, 7)
+    got = {"table": table.tobytes(), "queries": repr(build).encode(),
+           "requests": repr(plain).encode()}
+    assert {k: hashlib.sha256(v).hexdigest() for k, v in got.items()} == GOLDEN
+
+
+# (name, width): every table under bench/tables/, its width from its schema
+TABLES = [(n, len(SPEC.load("tables", n).schema())) for n in _found("tables")]
+# (name, n): every family under bench/families/, n a multiple it gives
+FAMILIES = [(n, 5 * SPEC.load("families", n).MULTIPLE)
+            for n in _found("families")]
+
+
+@pytest.mark.parametrize("table,width", TABLES)
 def test_tables_are_seeded_and_keep_their_widths_and_domains(table, width):
-    schema, a = datagen.make_table(table, 5000, 123)
-    _, b = datagen.make_table(table, 5000, 123)
-    _, c = datagen.make_table(table, 5000, 124)
+    schema, a = datagen.make_table(SPEC, table, 5000, 123)
+    _, b = datagen.make_table(SPEC, table, 5000, 123)
+    _, c = datagen.make_table(SPEC, table, 5000, 124)
     assert a.dtype == np.int32 and a.shape == (5000, width) == (5000, len(schema))
     assert np.array_equal(a, b) and not np.array_equal(a, c)
     assert (a >= 0).all() and (a < schema.doms[None, :]).all()
@@ -128,7 +284,7 @@ def test_tables_are_seeded_and_keep_their_widths_and_domains(table, width):
 
 
 def test_tpch_table_keeps_its_date_correlations():
-    schema, t = datagen.make_table("tpch", 20000, 5)
+    schema, t = datagen.make_table(SPEC, "tpch", 20000, 5)
     d = schema.dim
     ship, commit, receipt = (t[:, d(n)] for n in
                              ("l_shipdate", "l_commitdate", "l_receiptdate"))
@@ -137,12 +293,13 @@ def test_tpch_table_keeps_its_date_correlations():
     assert (t[:, d("o_orderdate")] <= ship).all()
 
 
-@pytest.mark.parametrize("family,n", [("tpch", 75)])
+@pytest.mark.parametrize("family,n", FAMILIES)
 def test_queries_are_seeded_and_in_domain(family, n):
-    schema = datagen.TABLES[family][0]()
-    qs = datagen.make_queries(family, schema, n, 9)
-    assert qs == datagen.make_queries(family, schema, n, 9)
-    assert qs != datagen.make_queries(family, schema, n, 10)
+    assert family in TABLE_OF, f"no configuration names family {family!r}"
+    schema = SPEC.load("tables", TABLE_OF[family]).schema()
+    qs = datagen.make_queries(SPEC, family, schema, n, 9)
+    assert qs == datagen.make_queries(SPEC, family, schema, n, 9)
+    assert qs != datagen.make_queries(SPEC, family, schema, n, 10)
     assert len(qs) == n
     for q in qs:
         for conj in q:
@@ -155,10 +312,10 @@ def test_queries_are_seeded_and_in_domain(family, n):
 
 
 def test_tpch_family_has_fifteen_templates_and_the_q19_disjunction():
-    schema = datagen.tpch_schema()
-    names = datagen.template_names("tpch", schema)
-    assert len(names) == 15
-    q19 = datagen.instance("tpch", schema, "q19", np.random.default_rng(0))
+    schema = _tpch_schema()
+    templates = datagen.templates(SPEC, "tpch", schema)
+    assert len(templates) == SPEC.load("families", "tpch").MULTIPLE == 15
+    q19 = templates["q19"](np.random.default_rng(0))
     assert len(q19) == 3 and all(len(c) == 8 for c in q19)
 
 
@@ -191,12 +348,12 @@ def test_schedule_gives_every_seed_the_same_gaps():
 
 
 def test_request_mixes_are_fixed_in_count():
-    schema = datagen.tpch_schema()
+    schema = _tpch_schema()
     cfg = _config("tpch_sf1")
     mix = SPEC.traffic("tpch_adhoc_fresh")
-    a, b = (tr.requests(mix, cfg, schema, 150, s) for s in (3, 4))
+    a, b = (tr.requests(SPEC, mix, cfg, schema, 150, s) for s in (3, 4))
     assert len(a) == len(b) == 150 and len({repr(q) for q in a}) > 100
-    assert a == tr.requests(mix, cfg, schema, 150, 3) and a != b
+    assert a == tr.requests(SPEC, mix, cfg, schema, 150, 3) and a != b
 
     def shapes(qs):  # the template an instance came from, by its atoms
         return Counter(tuple(tuple((x[0], x[1]) for x in c) for c in q)
@@ -437,7 +594,7 @@ def test_the_layout_is_sound_and_the_same_for_every_seed():
     from qdbench.system import Program
 
     cfg, _ = _tiny(("tpch_sf1", "bulk_load"))
-    a, b = (cells.set_up_layout(cfg, seed, Program(), lambda m: None)
+    a, b = (cells.set_up_layout(SPEC, cfg, seed, Program(), lambda m: None)
             for seed in (5, 2**31 + 11))
     assert a["layout_faults"] == b["layout_faults"] == 0
     assert cells.fingerprint(a["layout"]) == cells.fingerprint(b["layout"])
@@ -475,7 +632,7 @@ def _run(pair: tuple, program=None, seed: int = 2**31 + 7):
     from qdbench.system import Program
 
     cfg, mix = _tiny(pair)
-    return cells.run(cfg, mix, seed, 0.4, False, program or Program(),
+    return cells.run(SPEC, cfg, mix, seed, 0.4, False, program or Program(),
                      lambda m: None)
 
 

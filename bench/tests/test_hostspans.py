@@ -115,7 +115,7 @@ def test_a_tiny_traced_run_reports_the_program_spans(traffic):
 
     report = _module("qdbench_span_report", BENCH / "span_report.py")
     cfg, mix = _tiny(traffic)
-    out, spans = report.measure(cfg, mix, 2**31 + 5, 0.4, Program(),
+    out, spans = report.measure(SPEC, cfg, mix, 2**31 + 5, 0.4, Program(),
                                 lambda m: None)
     assert out.correct, out.checks
     assert out.layer["trace"].window_s > 0
