@@ -25,6 +25,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import spans
 from repro.core import predicates as preds
 from repro.core import query as qry
 from repro.core.qdtree import Node, QdTree, child_descs_all, singleton_tree
@@ -79,11 +80,13 @@ def best_cut_for_node(
     if not legal.any():
         return None
 
-    L, R = child_descs_all(node.desc, tree.cuts)
+    # only the legal cuts' children are scored; the others keep -1
+    ok = np.nonzero(legal)[0]
+    L, R = child_descs_all(node.desc, tree.cuts, ok)
     skip_l = _conj_skips(L, wt, tree.schema)
     skip_r = _conj_skips(R, wt, tree.schema)
-    contrib = left_sizes * skip_l + right_sizes * skip_r
-    contrib = np.where(legal, contrib, -1)
+    contrib = np.full(left_sizes.shape, -1, np.int64)
+    contrib[ok] = left_sizes[ok] * skip_l + right_sizes[ok] * skip_r
 
     # current contribution of n as a leaf
     parent = {
@@ -124,7 +127,8 @@ def build_greedy(
         node = frontier.pop(0)
         if node.size < 2 * cfg.min_block and not cfg.allow_small_child:
             continue
-        choice = best_cut_for_node(node, tree, cut_matrix, wt, cfg)
+        with spans.span(spans.BUILD_SCORE):
+            choice = best_cut_for_node(node, tree, cut_matrix, wt, cfg)
         if choice is None:
             continue
         cut_id, contrib = choice

@@ -86,15 +86,15 @@ def child_descs(
 
 
 def child_descs_all(
-    desc: NodeDesc, cuts: CutTable
+    desc: NodeDesc, cuts: CutTable, ids: np.ndarray
 ) -> tuple[dict[str, np.ndarray], dict[str, np.ndarray]]:
-    """Vectorized ``child_descs`` across *every* candidate cut.
+    """Vectorized ``child_descs`` across the candidate cuts ``ids``.
 
     Returns (left, right), each a dict of stacked arrays:
-      lo, hi : (n_cuts, ndims);  cat : (n_cuts, bits);  adv : (n_cuts, n_adv, 2)
-    Used by greedy construction to score all cuts in one shot.
+      lo, hi : (n, ndims);  cat : (n, bits);  adv : (n, n_adv, 2)
+    Used by greedy construction to score a node's cuts in one shot.
     """
-    n = cuts.n_cuts
+    n = ids.size
     L = {
         "lo": np.broadcast_to(desc.lo, (n, desc.lo.size)).copy(),
         "hi": np.broadcast_to(desc.hi, (n, desc.hi.size)).copy(),
@@ -102,32 +102,33 @@ def child_descs_all(
         "adv": np.broadcast_to(desc.adv, (n,) + desc.adv.shape).copy(),
     }
     R = {k: v.copy() for k, v in L.items()}
+    kind = cuts.kind[ids]
 
-    rng = cuts.kind == preds.KIND_RANGE
-    if rng.any():
-        idx = np.nonzero(rng)[0]
-        dims = cuts.dim[idx]
-        cps = cuts.cutpoint[idx]
-        L["hi"][idx, dims] = np.minimum(L["hi"][idx, dims], cps)
-        R["lo"][idx, dims] = np.maximum(R["lo"][idx, dims], cps)
+    rng = np.nonzero(kind == preds.KIND_RANGE)[0]
+    if rng.size:
+        dims = cuts.dim[ids[rng]]
+        cps = cuts.cutpoint[ids[rng]]
+        L["hi"][rng, dims] = np.minimum(L["hi"][rng, dims], cps)
+        R["lo"][rng, dims] = np.maximum(R["lo"][rng, dims], cps)
 
-    inc = cuts.kind == preds.KIND_IN
-    if inc.any():
-        idx = np.nonzero(inc)[0]
-        # in_mask is zero outside the cut's own dim segment, so AND-ing the
-        # complement must be limited to the segment.  Build per-cut segment
-        # masks once.
-        for i in idx:
-            seg = cuts.schema.cat_segment(int(cuts.dim[i]))
-            L["cat"][i, seg] &= cuts.in_mask[i, seg]
-            R["cat"][i, seg] &= ~cuts.in_mask[i, seg]
+    inc = np.nonzero(kind == preds.KIND_IN)[0]
+    if inc.size:
+        # in_mask is zero outside the cut's own dim segment, so the
+        # complement is taken inside the segment only
+        dims = cuts.dim[ids[inc]]
+        start = cuts.schema.cat_offsets[dims][:, None]
+        stop = start + cuts.schema.doms[dims][:, None]
+        bit = np.arange(desc.cat.size)[None, :]
+        outside = (bit < start) | (bit >= stop)
+        mask = cuts.in_mask[ids[inc]]
+        L["cat"][inc] &= mask | outside
+        R["cat"][inc] &= ~mask | outside
 
-    advc = cuts.kind == preds.KIND_ADV
-    if advc.any():
-        idx = np.nonzero(advc)[0]
-        aids = cuts.adv_id[idx]
-        L["adv"][idx, aids] = np.array([True, False])
-        R["adv"][idx, aids] = np.array([False, True])
+    advc = np.nonzero(kind == preds.KIND_ADV)[0]
+    if advc.size:
+        aids = cuts.adv_id[ids[advc]]
+        L["adv"][advc, aids] = np.array([True, False])
+        R["adv"][advc, aids] = np.array([False, True])
     return L, R
 
 

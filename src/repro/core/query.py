@@ -88,7 +88,7 @@ class WorkloadTensors:
     q_lo, q_hi  : (n_conj, ndims) int32 — numeric box (hi exclusive)
     q_cat       : (n_conj, bits) bool   — allowed categorical values
     q_adv       : (n_conj, n_adv) int8  — ADV_ANY / ADV_TRUE / ADV_FALSE
-    conj_query  : (n_conj,) int32       — owning query index
+    conj_query  : (n_conj,) int32       — owning query index, nondecreasing
     n_queries   : int
     """
 
@@ -102,6 +102,23 @@ class WorkloadTensors:
     @property
     def n_conjuncts(self) -> int:
         return int(self.q_lo.shape[0])
+
+    def cat_terms(self, schema: Schema) -> list:
+        """Per categorical column of ``schema`` (the one these tensors were
+        made for): its bit segment, the conjuncts that constrain it (allow
+        fewer than all its codes) and their segment rows as float32.
+        Computed once."""
+        terms = self.__dict__.get("_cat_terms")
+        if terms is None:
+            terms = []
+            for d in np.nonzero(schema.is_categorical)[0]:
+                seg = schema.cat_segment(int(d))
+                some = np.nonzero(~self.q_cat[:, seg].all(axis=1))[0]
+                terms.append(
+                    (seg, some, self.q_cat[some, seg].astype(np.float32))
+                )
+            self._cat_terms = terms
+        return terms
 
 
 @dataclasses.dataclass
@@ -191,6 +208,34 @@ class Workload:
 # ---------------------------------------------------------------------------
 # Intersection checks (numpy reference; kernel in kernels/query_intersect.py)
 # ---------------------------------------------------------------------------
+def cat_intersect(
+    desc_cat: np.ndarray,  # (L, bits)
+    wt: WorkloadTensors,
+    schema: Schema,
+) -> np.ndarray:
+    """(L, n_conj) bool — every categorical column of conjunct c shares at
+    least one code with description L.
+
+    A description must hold some code of every column, and of a column
+    that the conjunct constrains, one of the allowed codes: one float32
+    product of the 0/1 segments counts those, without the (L, n_conj,
+    dom) broadcast, and is exact while a domain is below 2**24.
+    """
+    terms = wt.cat_terms(schema)
+    out = np.ones((desc_cat.shape[0], wt.n_conjuncts), bool)
+    if not terms:
+        return out
+    starts = [seg.start for seg, _, _ in terms]
+    out &= np.logical_or.reduceat(desc_cat, starts, axis=1).all(axis=1)[
+        :, None
+    ]
+    dc = desc_cat.astype(np.float32)
+    for seg, some, q in terms:
+        if some.size:
+            out[:, some] &= (dc[:, seg] @ q.T) > 0
+    return out
+
+
 def conjuncts_intersect(
     desc_lo: np.ndarray,  # (L, ndims)
     desc_hi: np.ndarray,
@@ -202,19 +247,11 @@ def conjuncts_intersect(
     """(L, n_conj) bool — does block description L possibly contain records
     matching conjunct c?  Conservative (never false-negative)."""
     # numeric box overlap on every numeric dim: max(lo) < min(hi)
-    lo = np.maximum(desc_lo[:, None, :], wt.q_lo[None, :, :])
-    hi = np.minimum(desc_hi[:, None, :], wt.q_hi[None, :, :])
     numeric = ~schema.is_categorical
-    box_ok = (lo < hi)[:, :, numeric].all(axis=2)
-    # categorical: every constrained dim must share at least one value
-    cat_ok = np.ones(box_ok.shape, bool)
-    off = schema.cat_offsets
-    for d in np.nonzero(schema.is_categorical)[0]:
-        seg = slice(int(off[d]), int(off[d]) + schema.columns[d].dom)
-        inter = (
-            desc_cat[:, None, seg] & wt.q_cat[None, :, seg]
-        ).any(axis=2)
-        cat_ok &= inter
+    lo = np.maximum(desc_lo[:, None, numeric], wt.q_lo[None, :, numeric])
+    hi = np.minimum(desc_hi[:, None, numeric], wt.q_hi[None, :, numeric])
+    box_ok = (lo < hi).all(axis=2)
+    cat_ok = cat_intersect(desc_cat, wt, schema)
     # advanced bits: required polarity must be possible under the block
     adv_ok = np.ones(box_ok.shape, bool)
     n_adv = desc_adv.shape[1]
@@ -238,7 +275,10 @@ def queries_intersect(
     """
     L = conj_hits.shape[0]
     out = np.zeros((L, wt.n_queries), bool)
-    np.logical_or.at(out, (slice(None), wt.conj_query), conj_hits)
+    if wt.n_conjuncts:
+        # a query's conjuncts are contiguous (``tensorize`` order)
+        q, start = np.unique(wt.conj_query, return_index=True)
+        out[:, q] = np.logical_or.reduceat(conj_hits, start, axis=1)
     return out
 
 

@@ -103,6 +103,11 @@ def errorlog_int_schema() -> Schema:
 
 
 def make_errorlog_int(n_rows: int, seed: int = 0) -> tuple[Schema, np.ndarray]:
+    """A log table in the shape the paper's ErrorLog-Int is described by
+    (Sec 7.1).  That dataset is internal to Microsoft and its schema and
+    distributions are not published: the column names, domains, Zipf
+    exponents and correlations here are this repository's own invention,
+    not the paper's data."""
     schema = errorlog_int_schema()
     rng = np.random.default_rng(seed)
     n = n_rows
@@ -154,6 +159,11 @@ def errorlog_ext_schema() -> Schema:
 
 
 def make_errorlog_ext(n_rows: int, seed: int = 0) -> tuple[Schema, np.ndarray]:
+    """A log table in the shape the paper's ErrorLog-Ext is described by
+    (Sec 7.1).  That dataset is internal to Microsoft and its schema and
+    distributions are not published: the column names, domains, Zipf
+    exponents and correlations here are this repository's own invention,
+    not the paper's data."""
     schema = errorlog_ext_schema()
     rng = np.random.default_rng(seed)
     n = n_rows

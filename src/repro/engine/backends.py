@@ -239,82 +239,89 @@ def _ingest_jax_padded(records, valid, loc, ca, locate, depth, l_dump,
     from repro.core.routing import _in_lookup
 
     m = records.shape[0]
-    if n_adv:
-        adv = ca["adv"]
-        va = records[:, adv[:, 0]]
-        vb = records[:, adv[:, 2]]
-        op = adv[:, 1][None, :]
-        t = jnp.select(
-            [op == 0, op == 1, op == 2, op == 3, op == 4, op == 5],
-            [va < vb, va <= vb, va > vb, va >= vb, va == vb, va != vb],
-        ).astype(bool)  # select's int default would break ~t
+    scope = jax.named_scope
+    with scope(spans.STEP_PREDICATES):
+        if n_adv:
+            adv = ca["adv"]
+            va = records[:, adv[:, 0]]
+            vb = records[:, adv[:, 2]]
+            op = adv[:, 1][None, :]
+            t = jnp.select(
+                [op == 0, op == 1, op == 2, op == 3, op == 4, op == 5],
+                [va < vb, va <= vb, va > vb, va >= vb, va == vb, va != vb],
+            ).astype(bool)  # select's int default would break ~t
 
-    # kind-grouped predicate matrix: [range | IN | adv] segment columns
-    rng_m = records[:, ca["dim_r"]] < ca["cut_r"][None, :]
-    vals_i = records[:, ca["dim_i"]]
-    bitpos = jnp.clip(
-        vals_i + ca["off_i"][None, :], 0, ca["mask_i"].shape[1] - 1
-    )
-    inm = _in_lookup(ca["mask_i"], bitpos)
-    if n_adv:
-        advm = t[:, ca["advsel"]]
-    else:
-        advm = jnp.zeros((m, ca["advsel"].shape[0]), bool)
-    M = jnp.concatenate([rng_m, inm, advm], axis=1)
+        # kind-grouped predicate matrix: [range | IN | adv] segment columns
+        rng_m = records[:, ca["dim_r"]] < ca["cut_r"][None, :]
+        vals_i = records[:, ca["dim_i"]]
+        bitpos = jnp.clip(
+            vals_i + ca["off_i"][None, :], 0, ca["mask_i"].shape[1] - 1
+        )
+        with scope(spans.STEP_IN_LOOKUP):
+            inm = _in_lookup(ca["mask_i"], bitpos)
+        if n_adv:
+            advm = t[:, ca["advsel"]]
+        else:
+            advm = jnp.zeros((m, ca["advsel"].shape[0]), bool)
+        M = jnp.concatenate([rng_m, inm, advm], axis=1)
 
     bitvec = None
     if cat_gemm and cat_cols:
         # per-record categorical one-hot at the schema's bit layout,
         # feeding the stats matmul below
-        bitvec = jnp.concatenate(
-            [
-                (
-                    records[:, dd, None]
-                    == jnp.arange(card, dtype=records.dtype)[None, :]
-                ).astype(jnp.float32)
-                for dd, _off, card in cat_cols
-            ],
-            axis=1,
-        )
-    if locate == "paths":
-        bids = _locate_paths(M, loc)
-    else:
-        bids = _locate_descent(M, loc, depth)
+        with scope(spans.STEP_CAT_STATS):
+            bitvec = jnp.concatenate(
+                [
+                    (
+                        records[:, dd, None]
+                        == jnp.arange(card, dtype=records.dtype)[None, :]
+                    ).astype(jnp.float32)
+                    for dd, _off, card in cat_cols
+                ],
+                axis=1,
+            )
+    with scope(spans.STEP_LOCATE):
+        if locate == "paths":
+            bids = _locate_paths(M, loc)
+        else:
+            bids = _locate_descent(M, loc, depth)
 
     d = records.shape[1]
     i32 = jnp.iinfo(jnp.int32)
     agg = jnp.where(valid, bids, l_dump - 1).astype(jnp.int32)
-    lo = (
-        jnp.full((l_dump, d), i32.max, jnp.int32).at[agg].min(records)
-    )
-    hi = (
-        jnp.full((l_dump, d), i32.min, jnp.int32).at[agg].max(records)
-    )
+    with scope(spans.STEP_MINMAX):
+        lo = (
+            jnp.full((l_dump, d), i32.max, jnp.int32).at[agg].min(records)
+        )
+        hi = (
+            jnp.full((l_dump, d), i32.min, jnp.int32).at[agg].max(records)
+        )
 
-    cols = [jnp.ones((m, 1), jnp.float32)]
-    if bitvec is not None:
-        cols.append(bitvec)
-    if n_adv:
-        cols.append(t.astype(jnp.float32))
-        cols.append((~t).astype(jnp.float32))
-    onehot = (
-        agg[:, None] == jnp.arange(l_dump, dtype=jnp.int32)[None, :]
-    ).astype(jnp.float32)
-    stats = onehot.T @ jnp.concatenate(cols, axis=1)
-    counts = stats[:, 0].astype(jnp.int32)
-    pos = 1
-    if bitvec is not None:
-        cat = stats[:, pos : pos + bits] > 0
-        pos += bits
-    else:
-        cat = jnp.zeros((l_dump, bits), bool)
-        for dd, off, _card in cat_cols:
-            cat = cat.at[agg, off + records[:, dd]].max(True)
-    if n_adv:
-        advt = stats[:, pos : pos + n_adv] > 0
-        advf = stats[:, pos + n_adv : pos + 2 * n_adv] > 0
-    else:
-        advt = advf = jnp.zeros((l_dump, 1), bool)
+    with scope(spans.STEP_CAT_STATS):
+        cols = [jnp.ones((m, 1), jnp.float32)]
+        if bitvec is not None:
+            cols.append(bitvec)
+        if n_adv:
+            cols.append(t.astype(jnp.float32))
+            cols.append((~t).astype(jnp.float32))
+        onehot = (
+            agg[:, None] == jnp.arange(l_dump, dtype=jnp.int32)[None, :]
+        ).astype(jnp.float32)
+        stats = onehot.T @ jnp.concatenate(cols, axis=1)
+        counts = stats[:, 0].astype(jnp.int32)
+        pos = 1
+        if bitvec is not None:
+            cat = stats[:, pos : pos + bits] > 0
+            pos += bits
+        else:
+            cat = jnp.zeros((l_dump, bits), bool)
+            for dd, off, _card in cat_cols:
+                cat = cat.at[agg, off + records[:, dd]].max(True)
+        if n_adv:
+            advt = stats[:, pos : pos + n_adv] > 0
+            advf = stats[:, pos + n_adv : pos + 2 * n_adv] > 0
+        else:
+            advt = advf = jnp.zeros((l_dump, 1), bool)
     return bids, counts, lo, hi, cat, advt, advf
 
 
@@ -517,7 +524,9 @@ class JaxBackend(Backend):
             return CompiledPlan(
                 key=key, fn=fn, operands={"loc": loc, "ca": ca},
                 meta={"locate": locate, "depth": depth_bucket,
-                      "l_dump": l_dump},
+                      "l_dump": l_dump, "cat_gemm": cat_gemm,
+                      "in_cuts": (int(seg_i.size), ni_pad),
+                      "cat_bits": bits},
             )
 
         return cache.get(key, build)

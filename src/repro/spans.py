@@ -30,6 +30,23 @@ Serve (``QueryServer``):
   routing dispatch and the torn-epoch check (argument ``misses``).
 * ``serve.finish`` — per dispatch: cache inserts, tracker record and
   ticket completion.
+
+Device step: ``jax.named_scope`` names over the phases of the jax
+backend's fused ingest step (``_ingest_jax_padded``), carried into the
+``op_name`` metadata of the compiled step's device ops; they change no
+op:
+
+* ``step.predicates`` — the range, IN and advanced predicate matrix;
+* ``step.in_lookup`` — inside it, the IN cuts' per-cut bit lookup;
+* ``step.locate`` — each row's block (path-matrix product or descent);
+* ``step.minmax`` — the per-block minimum and maximum scatters;
+* ``step.cat_stats`` — the categorical one-hot and the one-hot stats
+  product (counts, categorical bits, advanced flags).
+
+Build (``core.greedy.build_greedy``):
+
+* ``build.score`` — per node the greedy builder tries to split: the
+  legal cuts' children described and scored against the workload.
 """
 
 from __future__ import annotations
@@ -48,7 +65,17 @@ SERVE_PROBE = "serve.probe"
 SERVE_ROUTE = "serve.route"
 SERVE_FINISH = "serve.finish"
 
+STEP_PREDICATES = "step.predicates"
+STEP_IN_LOOKUP = "step.in_lookup"
+STEP_LOCATE = "step.locate"
+STEP_MINMAX = "step.minmax"
+STEP_CAT_STATS = "step.cat_stats"
+
+BUILD_SCORE = "build.score"
+
 ENGINE = (ENGINE_INGEST, ENGINE_STAGE, ENGINE_DEVICE_WAIT, ENGINE_FOLD,
           ENGINE_PUBLISH)
 SERVE = (SERVE_COALESCE, SERVE_DISPATCH, SERVE_PROBE, SERVE_ROUTE,
          SERVE_FINISH)
+STEP = (STEP_PREDICATES, STEP_IN_LOOKUP, STEP_LOCATE, STEP_MINMAX,
+        STEP_CAT_STATS)

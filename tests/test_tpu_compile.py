@@ -161,3 +161,49 @@ def test_jax_ingest_step_compiles(f32):
         shaped((M, D), jnp.int32), shaped((M,), jnp.bool_), **kw
     ).compile()
     assert " while(" not in compiled.as_text()
+
+
+# categorical widths of the tpcds_sf1 benchmark table (TPC-DS store_sales
+# joined to its dimensions): 24 columns, 2,241 bits, beside 11 numeric
+STAR_CAT_DOMS = (5, 12, 7, 60, 24, 10, 100, 712, 1000, 100, 12, 51, 30, 30,
+                 2, 2, 5, 7, 6, 10, 51, 1, 2, 2)
+
+
+def test_jax_ingest_step_compiles_at_star_width(f32):
+    """The same step at the tpcds_sf1 cell's geometry: 65,536 rows x 35
+    columns with 2,241 categorical bits, leaf bucket 1024, grouped cut
+    bucket 32 range + 64 IN + 4.  The plan keeps the path-matrix product
+    and the one-hot stats product, and the step fits one chip."""
+    from repro.core.predicates import Column, CutTableBuilder, Schema
+    from repro.core.qdtree import singleton_tree
+    from repro.engine import backends
+    from repro.engine.plan import PlanCache
+
+    cols = [Column(f"n{i}", "numeric", 1000) for i in range(11)]
+    cols += [Column(f"c{i}", "categorical", d)
+             for i, d in enumerate(STAR_CAT_DOMS)]
+    schema = Schema(tuple(cols))
+    assert schema.total_cat_bits == 2241
+    tree = singleton_tree(schema, CutTableBuilder(schema).build(),
+                          np.arange(1)).freeze()
+    plan = backends.get_backend("jax")._ingest_plan(tree, PlanCache())
+    assert plan.meta["cat_gemm"] and plan.meta["cat_bits"] == 2241
+    one_chip = f32(1).sharding
+    seg = {"dim_r": 32, "cut_r": 32, "dim_i": 64, "off_i": 64,
+           "mask_i": 64, "advsel": 4, "adv": 1}
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    kw = dict(plan.fn.keywords)
+    kw.update(
+        loc={"path_diff": f32(100, 1024), "path_len": f32(1024)},
+        ca={k: shaped((seg[k],) + a.shape[1:], a.dtype)
+            for k, a in kw["ca"].items()},
+        l_dump=1025, depth=32, locate="paths",
+    )
+    compiled = plan.fn.func.lower(
+        shaped((M, len(cols)), jnp.int32), shaped((M,), jnp.bool_), **kw
+    ).compile()
+    assert " while(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
