@@ -1,6 +1,8 @@
 """Batched data routing through a frozen qd-tree (paper Sec 3.1).
 
-Three interchangeable backends, all bit-identical:
+Three interchangeable backends, all bit-identical on records whose
+categorical codes lie inside their column's domain (the schema forbids
+any other; a code outside it may read differently on each backend):
 
 * ``FrozenQdTree.route``      — numpy oracle (core/qdtree.py)
 * engine "jax" backend        — jitted jnp level-synchronous descent

@@ -216,8 +216,14 @@ def _ingest_jax_padded(records, valid, loc, ca, locate, depth, l_dump,
     * ``ca`` holds only the cuts the tree's internal nodes reference
       (pruned + remapped by ``_ingest_plan``), *grouped by kind*
       (``[range | IN | adv]`` segments, each padded to its own bucket),
-      so range cuts are pure vector compares and the per-cut bit gathers
-      run only over the IN segment;
+      so range cuts are pure vector compares;
+    * the IN segment is one product of the row's categorical one-hot
+      (``bitvec``, its columns in ``cat_cols`` order) with the IN masks
+      permuted into that order, ``bitvec @ mask_iT``: a mask is nonzero
+      only in its column's bit segment and a row has one bit set per
+      segment, so every cell is exactly 0 or 1 and equals the bit lookup
+      at any matmul precision.  A code outside its column's domain, which
+      the schema forbids, reads False here;
     * each row's block comes from one path-matrix product against the
       predicate matrix (``locate="paths"``, :func:`_locate_paths`), or
       from ``depth`` rounds of per-row gathers (``"descent"``) on a plan
@@ -233,13 +239,26 @@ def _ingest_jax_padded(records, valid, loc, ca, locate, depth, l_dump,
     aggregates convert to the tightener's int64 partials exactly.
     ``cat_cols`` is ``((dim, bit_offset, cardinality), ...)``;
     ``cat_gemm`` is False when the schema's bit layout is not contiguous
-    in dim order, falling back to per-dim scatters.
+    in dim order: the stats then fall back to per-dim scatters.
     """
     count_trace("ingest:jax")
-    from repro.core.routing import _in_lookup
-
     m = records.shape[0]
     scope = jax.named_scope
+    bitvec = None
+    if cat_cols:
+        # per-record categorical one-hot in ``cat_cols`` order, feeding
+        # the IN product and, at the schema's bit layout, the stats matmul
+        with scope(spans.STEP_CAT_STATS):
+            bitvec = jnp.concatenate(
+                [
+                    (
+                        records[:, dd, None]
+                        == jnp.arange(card, dtype=records.dtype)[None, :]
+                    ).astype(jnp.float32)
+                    for dd, _off, card in cat_cols
+                ],
+                axis=1,
+            )
     with scope(spans.STEP_PREDICATES):
         if n_adv:
             adv = ca["adv"]
@@ -253,33 +272,20 @@ def _ingest_jax_padded(records, valid, loc, ca, locate, depth, l_dump,
 
         # kind-grouped predicate matrix: [range | IN | adv] segment columns
         rng_m = records[:, ca["dim_r"]] < ca["cut_r"][None, :]
-        vals_i = records[:, ca["dim_i"]]
-        bitpos = jnp.clip(
-            vals_i + ca["off_i"][None, :], 0, ca["mask_i"].shape[1] - 1
-        )
-        with scope(spans.STEP_IN_LOOKUP):
-            inm = _in_lookup(ca["mask_i"], bitpos)
+        if bitvec is not None:
+            with scope(spans.STEP_IN_LOOKUP):
+                inm = jnp.dot(
+                    bitvec, ca["mask_iT"],
+                    preferred_element_type=jnp.float32,
+                ) > 0.5
+        else:  # no categorical column, so no IN cut
+            inm = jnp.zeros((m, ca["mask_iT"].shape[1]), bool)
         if n_adv:
             advm = t[:, ca["advsel"]]
         else:
             advm = jnp.zeros((m, ca["advsel"].shape[0]), bool)
         M = jnp.concatenate([rng_m, inm, advm], axis=1)
 
-    bitvec = None
-    if cat_gemm and cat_cols:
-        # per-record categorical one-hot at the schema's bit layout,
-        # feeding the stats matmul below
-        with scope(spans.STEP_CAT_STATS):
-            bitvec = jnp.concatenate(
-                [
-                    (
-                        records[:, dd, None]
-                        == jnp.arange(card, dtype=records.dtype)[None, :]
-                    ).astype(jnp.float32)
-                    for dd, _off, card in cat_cols
-                ],
-                axis=1,
-            )
     with scope(spans.STEP_LOCATE):
         if locate == "paths":
             bids = _locate_paths(M, loc)
@@ -299,7 +305,7 @@ def _ingest_jax_padded(records, valid, loc, ca, locate, depth, l_dump,
 
     with scope(spans.STEP_CAT_STATS):
         cols = [jnp.ones((m, 1), jnp.float32)]
-        if bitvec is not None:
+        if cat_gemm and bitvec is not None:
             cols.append(bitvec)
         if n_adv:
             cols.append(t.astype(jnp.float32))
@@ -310,7 +316,7 @@ def _ingest_jax_padded(records, valid, loc, ca, locate, depth, l_dump,
         stats = onehot.T @ jnp.concatenate(cols, axis=1)
         counts = stats[:, 0].astype(jnp.int32)
         pos = 1
-        if bitvec is not None:
+        if cat_gemm and bitvec is not None:
             cat = stats[:, pos : pos + bits] > 0
             pos += bits
         else:
@@ -428,7 +434,7 @@ class JaxBackend(Backend):
         # the ingest plan evaluates only the cuts the tree references —
         # the candidate table is typically several times larger — and
         # groups them by kind so range cuts stay pure compares and the
-        # per-cut bit gathers run only over the IN segment
+        # IN masks form one operand of the one-hot product
         from repro.core import predicates as preds
 
         used = np.unique(tree.cut_id[tree.cut_id >= 0]).astype(np.int64)
@@ -487,21 +493,6 @@ class JaxBackend(Backend):
                 out[: seg.size] = x[seg]
                 return out
 
-            off_full = ca_full["cat_offset"][ca_full["dim"]]
-            ca_np = {
-                "dim_r": _segpad(ca_full["dim"], seg_r, nr_pad, 0),
-                "cut_r": _segpad(ca_full["cutpoint"], seg_r, nr_pad, 0),
-                "dim_i": _segpad(ca_full["dim"], seg_i, ni_pad, 0),
-                "off_i": _segpad(off_full, seg_i, ni_pad, 0),
-                "mask_i": _segpad(ca_full["in_mask"], seg_i, ni_pad,
-                                  False),
-                "advsel": _segpad(ca_full["adv_id"], seg_a, na_pad, 0),
-                "adv": ca_full["adv"],
-            }
-            if ca_np["mask_i"].shape[1] == 0:  # no cat bits anywhere
-                ca_np["mask_i"] = np.zeros((ni_pad, 1), bool)
-            loc = {k: jnp.asarray(v) for k, v in loc_np.items()}
-            ca = {k: jnp.asarray(v) for k, v in ca_np.items()}
             off = np.maximum(schema.cat_offsets, 0)
             bits = max(int(schema.total_cat_bits), 1)
             cat_cols = []
@@ -516,6 +507,22 @@ class JaxBackend(Backend):
             cat_gemm = cat_gemm and (
                 not cat_cols or running == int(schema.total_cat_bits)
             )
+            # IN masks as the product's (bits, IN cuts) operand, their bit
+            # columns permuted into the one-hot's ``cat_cols`` order
+            bit_order = np.concatenate(
+                [np.arange(o, o + c) for _d, o, c in cat_cols]
+                or [np.zeros(0, np.int64)]
+            )
+            mask_i = _segpad(ca_full["in_mask"], seg_i, ni_pad, False)
+            ca_np = {
+                "dim_r": _segpad(ca_full["dim"], seg_r, nr_pad, 0),
+                "cut_r": _segpad(ca_full["cutpoint"], seg_r, nr_pad, 0),
+                "mask_iT": mask_i[:, bit_order].T.astype(np.float32),
+                "advsel": _segpad(ca_full["adv_id"], seg_a, na_pad, 0),
+                "adv": ca_full["adv"],
+            }
+            loc = {k: jnp.asarray(v) for k, v in loc_np.items()}
+            ca = {k: jnp.asarray(v) for k, v in ca_np.items()}
             fn = functools.partial(
                 _ingest_jax_padded, loc=loc, ca=ca, locate=locate,
                 depth=depth_bucket, l_dump=l_dump, cat_cols=tuple(cat_cols),
@@ -526,6 +533,7 @@ class JaxBackend(Backend):
                 meta={"locate": locate, "depth": depth_bucket,
                       "l_dump": l_dump, "cat_gemm": cat_gemm,
                       "in_cuts": (int(seg_i.size), ni_pad),
+                      "in_lookup": "product" if cat_cols else "none",
                       "cat_bits": bits},
             )
 

@@ -37,7 +37,8 @@ backend's fused ingest step (``_ingest_jax_padded``), carried into the
 op:
 
 * ``step.predicates`` — the range, IN and advanced predicate matrix;
-* ``step.in_lookup`` — inside it, the IN cuts' per-cut bit lookup;
+* ``step.in_lookup`` — inside it, the IN cuts' lookup (the one-hot
+  product with the IN masks);
 * ``step.locate`` — each row's block (path-matrix product or descent);
 * ``step.minmax`` — the per-block minimum and maximum scatters;
 * ``step.cat_stats`` — the categorical one-hot and the one-hot stats

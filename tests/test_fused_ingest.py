@@ -106,10 +106,27 @@ def test_fused_ingest_bit_identical_to_two_pass(data):
     assert _trees_identical(fused, legacy)
 
 
-def _kind_tree(kinds, seed, splits, m=512):
+class _ScatteredBitsSchema(Schema):
+    """Categorical bit segments laid out in reverse dim order.  No real
+    schema does this; the jax ingest plan's one-hot is then not at the
+    schema's bit layout, so the IN masks are permuted into its order and
+    the stats fall back to per-dim scatters."""
+
+    @property
+    def cat_offsets(self) -> np.ndarray:
+        off = np.full(self.ndims, -1, dtype=np.int32)
+        pos = 0
+        for i in reversed(range(self.ndims)):
+            if self.columns[i].kind == preds.KIND_CATEGORICAL:
+                off[i] = pos
+                pos += self.columns[i].dom
+        return off
+
+
+def _kind_tree(kinds, seed, splits, m=512, schema_cls=Schema):
     """Records and a random frozen tree whose cut table holds only cuts of
     ``kinds`` (``"range"``, ``"in"``, ``"adv"``)."""
-    schema = Schema((
+    schema = schema_cls((
         Column("x", "numeric", 64),
         Column("y", "numeric", 32),
         Column("c", "categorical", 6),
@@ -146,27 +163,33 @@ def _used_kinds(tree) -> set:
 
 
 @pytest.mark.parametrize(
-    "kinds,splits,m,locate",
+    "kinds,splits,m,locate,layout",
     [
-        (("range",), 12, 512, "paths"),
-        (("in",), 12, 512, "paths"),
-        (("range", "adv"), 12, 512, "paths"),
-        ((), 0, 512, "paths"),
-        (("range", "in", "adv"), 12, 300, "paths"),
-        (("range", "in", "adv"), 12, 300, "descent"),
+        (("range",), 12, 512, "paths", "contiguous"),
+        (("in",), 12, 512, "paths", "contiguous"),
+        (("range", "adv"), 12, 512, "paths", "contiguous"),
+        ((), 0, 512, "paths", "contiguous"),
+        (("range", "in", "adv"), 12, 300, "paths", "contiguous"),
+        (("range", "in", "adv"), 12, 300, "descent", "contiguous"),
+        (("in",), 12, 300, "paths", "scattered"),
+        (("range", "in", "adv"), 12, 300, "paths", "scattered"),
     ],
     ids=["range_only", "in_heavy", "advanced", "single_leaf",
-         "padded_rows", "past_crossover"],
+         "padded_rows", "past_crossover", "in_heavy_scattered_bits",
+         "mixed_scattered_bits"],
 )
 def test_jax_ingest_locate_matches_oracle(monkeypatch, kinds, splits, m,
-                                          locate):
+                                          locate, layout):
     """The jax step's leaf location — the path-matrix product, or the
-    descent on a plan past ``PATHS_MAX_CELLS`` — reproduces the numpy
-    reference bit for bit: bids and every aggregate, padded rows (a batch
-    off its row bucket) included."""
+    descent on a plan past ``PATHS_MAX_CELLS`` — and its IN lookup, the
+    one-hot product with the IN masks, also where the schema's bit layout
+    is not contiguous in dim order — reproduce the numpy reference bit
+    for bit: bids and every aggregate, padded rows (a batch off its row
+    bucket) included."""
     if locate == "descent":
         monkeypatch.setattr(backends, "PATHS_MAX_CELLS", 0)
-    records, tree = _kind_tree(kinds, 3, splits)
+    schema_cls = _ScatteredBitsSchema if layout == "scattered" else Schema
+    records, tree = _kind_tree(kinds, 3, splits, schema_cls=schema_cls)
     assert _used_kinds(tree) == set(kinds)
     assert (tree.n_leaves == 1) == (splits == 0)
     batch = records[:m]
@@ -174,6 +197,8 @@ def test_jax_ingest_locate_matches_oracle(monkeypatch, kinds, splits, m,
     bids, partial = eng.fused_step(batch)
     plan = backends.get_backend("jax")._ingest_plan(eng.tree, eng.plans)
     assert plan.meta["locate"] == locate
+    assert plan.meta["cat_gemm"] == (layout == "contiguous")
+    assert plan.meta["in_lookup"] == "product"
     want_bids, want_partial = fused_ingest_ref(tree, batch)
     np.testing.assert_array_equal(bids, want_bids)
     assert _partials_identical(partial, want_partial)

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import inspect
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +75,31 @@ def _assert_mosaic_kernel(lowered) -> None:
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _segment_operands(ca, seg, shaped) -> dict:
+    """The ingest plan's cut operands at a cell's segment buckets ``seg``:
+    the cut axis leads, except in the transposed IN masks."""
+    assert set(ca) == set(seg)
+    return {
+        k: shaped((a.shape[0], seg[k]) if k == "mask_iT"
+                  else (seg[k],) + a.shape[1:], a.dtype)
+        for k, a in ca.items()
+    }
+
+
+def _assert_in_lookup_is_a_product(hlo: str) -> None:
+    """No gather of the compiled step carries ``step.in_lookup`` in its
+    ``op_name``; a dot or convolution does."""
+    from repro import spans
+
+    ops = set()
+    for line in hlo.splitlines():
+        if spans.STEP_IN_LOOKUP in line and " = " in line:
+            rhs = line.split(" = ", 1)[1]
+            ops.add(re.search(r"\s([a-z][a-z0-9-]*)\(", rhs).group(1))
+    assert "gather" not in ops, ops
+    assert ops & {"dot", "convolution"}, ops
+
+
 @pytest.mark.parametrize("leaf_bucket", [128, 1024, fk.MAX_LEAF_BUCKET])
 def test_fused_ingest_compiles(f32, leaf_bucket):
     _assert_mosaic_kernel(fk.fused_ingest_pallas.lower(
@@ -130,7 +156,8 @@ def test_jax_ingest_step_compiles(f32):
     """The default backend's fused ingest step at the tpch_sf1 cell's
     geometry: 65,536 rows x 21 columns, leaf bucket 1024, grouped cut
     bucket 64 range + 32 IN + 4 advanced.  Rows are located by the
-    path-matrix product, so the compiled step has no descent loop."""
+    path-matrix product, so the compiled step has no descent loop, and
+    the IN cuts are a product with the one-hot, not a gather."""
     from repro.core.predicates import OP_LT, CutTableBuilder
     from repro.core.qdtree import singleton_tree
     from repro.engine import backends
@@ -143,9 +170,9 @@ def test_jax_ingest_step_compiles(f32):
     tree = singleton_tree(schema, b.build(), np.arange(1)).freeze()
     plan = backends.get_backend("jax")._ingest_plan(tree, PlanCache())
     assert plan.meta["locate"] == "paths"
+    assert plan.meta["in_lookup"] == "product"
     one_chip = f32(1).sharding
-    seg = {"dim_r": 64, "cut_r": 64, "dim_i": 32, "off_i": 32,
-           "mask_i": 32, "advsel": 4, "adv": A}
+    seg = {"dim_r": 64, "cut_r": 64, "mask_iT": 32, "advsel": 4, "adv": A}
 
     def shaped(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -153,14 +180,14 @@ def test_jax_ingest_step_compiles(f32):
     kw = dict(plan.fn.keywords)
     kw.update(
         loc={"path_diff": f32(100, 1024), "path_len": f32(1024)},
-        ca={k: shaped((seg[k],) + a.shape[1:], a.dtype)
-            for k, a in kw["ca"].items()},
+        ca=_segment_operands(kw["ca"], seg, shaped),
         l_dump=1025, depth=32,
     )
     compiled = plan.fn.func.lower(
         shaped((M, D), jnp.int32), shaped((M,), jnp.bool_), **kw
     ).compile()
     assert " while(" not in compiled.as_text()
+    _assert_in_lookup_is_a_product(compiled.as_text())
 
 
 # categorical widths of the tpcds_sf1 benchmark table (TPC-DS store_sales
@@ -173,7 +200,8 @@ def test_jax_ingest_step_compiles_at_star_width(f32):
     """The same step at the tpcds_sf1 cell's geometry: 65,536 rows x 35
     columns with 2,241 categorical bits, leaf bucket 1024, grouped cut
     bucket 32 range + 64 IN + 4.  The plan keeps the path-matrix product
-    and the one-hot stats product, and the step fits one chip."""
+    and the one-hot stats product, evaluates the IN cuts by the one-hot
+    product, and the step fits one chip."""
     from repro.core.predicates import Column, CutTableBuilder, Schema
     from repro.core.qdtree import singleton_tree
     from repro.engine import backends
@@ -188,9 +216,9 @@ def test_jax_ingest_step_compiles_at_star_width(f32):
                           np.arange(1)).freeze()
     plan = backends.get_backend("jax")._ingest_plan(tree, PlanCache())
     assert plan.meta["cat_gemm"] and plan.meta["cat_bits"] == 2241
+    assert plan.meta["in_lookup"] == "product"
     one_chip = f32(1).sharding
-    seg = {"dim_r": 32, "cut_r": 32, "dim_i": 64, "off_i": 64,
-           "mask_i": 64, "advsel": 4, "adv": 1}
+    seg = {"dim_r": 32, "cut_r": 32, "mask_iT": 64, "advsel": 4, "adv": 1}
 
     def shaped(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -198,12 +226,12 @@ def test_jax_ingest_step_compiles_at_star_width(f32):
     kw = dict(plan.fn.keywords)
     kw.update(
         loc={"path_diff": f32(100, 1024), "path_len": f32(1024)},
-        ca={k: shaped((seg[k],) + a.shape[1:], a.dtype)
-            for k, a in kw["ca"].items()},
+        ca=_segment_operands(kw["ca"], seg, shaped),
         l_dump=1025, depth=32, locate="paths",
     )
     compiled = plan.fn.func.lower(
         shaped((M, len(cols)), jnp.int32), shaped((M,), jnp.bool_), **kw
     ).compile()
     assert " while(" not in compiled.as_text()
+    _assert_in_lookup_is_a_product(compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

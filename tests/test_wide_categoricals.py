@@ -188,7 +188,8 @@ def _star_tree(m=3000, splits=90):
 
 def test_jax_ingest_at_two_thousand_bits_matches_numpy():
     """At a star schema's width the jax plan keeps the path-matrix
-    product and the one-hot stats product, names its IN cuts and bits,
+    product and the one-hot stats product, evaluates its IN cuts by the
+    one-hot product with the IN masks, names its IN cuts and bits,
     and its step equals the numpy oracle bit for bit (a padded batch
     too); the step's device ops carry the phase scopes."""
     records, tree = _star_tree()
@@ -200,6 +201,7 @@ def test_jax_ingest_at_two_thousand_bits_matches_numpy():
     n_in = int((kinds == preds.KIND_IN).sum())
     assert n_in > 20
     assert plan.meta["locate"] == "paths" and plan.meta["cat_gemm"]
+    assert plan.meta["in_lookup"] == "product"
     assert plan.meta["cat_bits"] == bits
     assert plan.meta["in_cuts"][0] == n_in <= plan.meta["in_cuts"][1]
 
